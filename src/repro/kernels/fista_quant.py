@@ -11,7 +11,7 @@ TPU-native replacement for the paper's sequential coordinate descent
 and both scans are lowered to *blocked triangular matmuls on the MXU*:
 rows are laid out (nb, T) with T=128 lanes; within-block cumsum is
 X @ triu_ones(T) (one MXU op), across-block offsets are a second tiny
-triangular matmul; the suffix sum reuses the same cumsum
+(strictly lower) triangular matmul; the suffix sum reuses the same cumsum
 (suffix = total - cumsum + x). One grid step = one tensor row, so a whole
 model's PTQ is a single kernel launch.
 
@@ -29,37 +29,44 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
+def _blocked_cumsum(x, triu_t, tril_nb_strict):
+    """(nb, T) row-major cumulative sum via two triangular matmuls.
 
-def _blocked_cumsum(x, triu_t, triu_nb_strict):
-    """(nb, T) row-major cumulative sum via two triangular matmuls."""
-    within = jnp.dot(x, triu_t, preferred_element_type=jnp.float32)   # (nb, T)
-    bsums = within[:, -1]                                             # (nb,)
-    offsets = jnp.dot(bsums[None, :], triu_nb_strict,
-                      preferred_element_type=jnp.float32)[0]          # (nb,)
-    return within + offsets[:, None]
+    Block b's offset is the sum of the earlier blocks' totals, i.e. lane
+    T-1 of ``tril_strict @ within``; every slice is static (Mosaic lowers
+    no dynamic_slice, which integer indexing like ``within[:, -1]`` emits).
+    Both matmuls run at f32 contract precision: they stand in for an exact
+    f32 scan (``ref.ref_fista``'s cumsum), not for a bf16 MXU pass.
+    """
+    T = x.shape[1]
+    hi = lax.Precision.HIGHEST
+    within = jnp.dot(x, triu_t, precision=hi,
+                     preferred_element_type=jnp.float32)              # (nb, T)
+    offsets = jnp.dot(tril_nb_strict, within, precision=hi,
+                      preferred_element_type=jnp.float32)[:, T - 1:]  # (nb, 1)
+    return within + offsets
 
 
 def _kernel(nsteps, w_ref, d_ref, n_ref, lam_ref, eta_ref, triu_t_ref,
-            triu_nb_ref, alpha_ref):
+            tril_nb_ref, alpha_ref):
     w = w_ref[0]        # (nb, T)
     d = d_ref[0]
     n = n_ref[0]
     lam = lam_ref[0]
-    eta = eta_ref[0, 0, 0]
+    eta = eta_ref[0]    # (1, 1), broadcasts
     triu_t = triu_t_ref[...]
-    triu_nb = triu_nb_ref[...]
+    tril_nb = tril_nb_ref[...]
+    nb, T = w.shape
 
     ones = jnp.ones_like(w)
 
     def body(i, carry):
         x_prev, y, t = carry
-        recon = _blocked_cumsum(y * d, triu_t, triu_nb)
+        recon = _blocked_cumsum(y * d, triu_t, tril_nb)
         r = n * (w - recon)
-        cums = _blocked_cumsum(r, triu_t, triu_nb)
-        total = cums[-1, -1]
+        cums = _blocked_cumsum(r, triu_t, tril_nb)
+        total = cums[nb - 1:, T - 1:]                                 # (1, 1)
         suffix = total - cums + r
         grad = -d * suffix
         v = y - eta * grad
@@ -91,7 +98,8 @@ def fista_quant(
     B, nb, T = w.shape
     assert T == block_t, (w.shape, block_t)
     triu_t = jnp.triu(jnp.ones((T, T), jnp.float32))
-    triu_nb = jnp.triu(jnp.ones((nb, nb), jnp.float32), k=1)  # strict: excl. own block
+    # strict: block b sums blocks b' < b only
+    tril_nb = jnp.tril(jnp.ones((nb, nb), jnp.float32), k=-1)
     row = pl.BlockSpec((1, nb, T), lambda b: (b, 0, 0))
     return pl.pallas_call(
         functools.partial(_kernel, n_iters),
@@ -102,8 +110,8 @@ def fista_quant(
                   pl.BlockSpec((nb, nb), lambda b: (0, 0))],
         out_specs=row,
         out_shape=jax.ShapeDtypeStruct((B, nb, T), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)
         ),
         interpret=interpret,
-    )(w, d, n, lam, eta, triu_t, triu_nb)
+    )(w, d, n, lam, eta, triu_t, tril_nb)

@@ -14,7 +14,12 @@ This kernel walks each sequence's block table on-core instead:
   (SMEM) so page ids are known before the body runs. Per page the kernel
   issues a *conditional* DMA - frozen pages copy packed codes + the two
   (L,) codebooks, hot pages copy the fp tile - so cold context crosses HBM
-  at ~4 bits/value and is dequantized (`cb[codes]`) in VMEM. The DMA is
+  at ~4 bits/value and is dequantized (`cb[codes]`) in VMEM. The codebooks
+  land in SMEM and the dequant is an L-way compare-and-select against their
+  scalars (Mosaic lowers no 1-D vector gather). A codebook is too narrow to
+  slice out of a lane-tiled pool on its own, so the wrapper views each
+  pool as 128-lane rows of ``128 // Lp`` codebooks (``codebook_rows``) and
+  the kernel copies the row that holds the page's codebook. The DMA is
   double-buffered by default: two VMEM slots with ping-pong semaphore
   banks, page j+1's copy started before page j's wait so it overlaps the
   dequant + flash step (serial single-slot variant kept for the benchmark
@@ -53,9 +58,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 BIG_NEG = -2.3819763e38
 
 
@@ -63,34 +65,66 @@ BIG_NEG = -2.3819763e38
 
 
 def pack4(codes: jax.Array) -> jax.Array:
-    """Pack two 4-bit codes per byte along the last dim (must be even).
+    """Pack two 4-bit codes per byte along a page's token axis.
 
-    Split-half layout: byte i holds codes[i] (low nibble) and codes[i + D/2]
-    (high nibble), so unpacking is a concatenate - lane-friendly on TPU,
-    where a minor-dim interleave would shuffle within vector registers.
+    ``codes`` is page-shaped (..., bs, Hkv, Dh) with bs even. Split-half
+    layout: byte (t, h, d) holds codes[t, h, d] (low nibble) and
+    codes[t + bs/2, h, d] (high nibble), so the packed page keeps the full
+    Dh lane width (a TPU tile is 128 lanes wide; a half-width lane dim can
+    be neither sliced per page nor DMA'd) and unpacking is a concatenate
+    over the untiled token axis.
     """
-    D = codes.shape[-1]
-    assert D % 2 == 0, f"pack4 needs an even last dim, got {D}"
-    lo, hi = codes[..., : D // 2], codes[..., D // 2:]
+    bs = codes.shape[-3]
+    assert bs % 2 == 0, f"pack4 needs an even page size, got {bs}"
+    lo, hi = codes[..., : bs // 2, :, :], codes[..., bs // 2:, :, :]
     return (lo.astype(jnp.uint8) | (hi.astype(jnp.uint8) << 4))
 
 
 def unpack4(packed: jax.Array) -> jax.Array:
-    """Inverse of pack4: (..., Dc) uint8 -> (..., 2*Dc) int32 codes."""
-    lo = (packed & 0xF).astype(jnp.int32)
-    hi = (packed >> 4).astype(jnp.int32)
-    return jnp.concatenate([lo, hi], axis=-1)
+    """Inverse of pack4: (..., bs/2, Hkv, Dh) uint8 -> (..., bs, Hkv, Dh)
+    int32 codes."""
+    wide = packed.astype(jnp.int32)     # Mosaic has no 8-bit shifts
+    return jnp.concatenate([wide & 0xF, wide >> 4], axis=-3)
 
 
 # ------------------------------------------------------------ kernel body
 
 
-def _kernel(bs, Hkv, G, W, Dh, scale, softcap, quantized, packed,
+LANES = 128
+
+
+def codebook_lookup(idx: jax.Array, cb_at, L: int) -> jax.Array:
+    """``cb[idx]`` for an (L,) codebook whose entries ``cb_at(l)`` are
+    scalars (SMEM reads in-kernel), as an L-way compare-and-select.
+
+    Mosaic lowers no vector gather from a 1-D table. Exactly one of the L
+    selects matches each element, so the result equals ``jnp.take``
+    bit-for-bit."""
+    out = jnp.zeros(idx.shape, jnp.float32)
+    for l in range(L):
+        out = jnp.where(idx == l, cb_at(l), out)
+    return out
+
+
+def codebook_rows(cb: jax.Array) -> tuple[jax.Array, int]:
+    """View an (n, L) codebook pool as (rows, 128) f32 lane rows, each
+    holding ``128 // Lp`` codebooks padded to Lp (the next power of two
+    >= L). Returns (rows, Lp); codebook i starts at lane (i * Lp) % 128 of
+    row (i * Lp) // 128."""
+    n, L = cb.shape
+    assert L <= LANES, f"codebooks wider than {LANES} entries: {L}"
+    Lp = 1 << (L - 1).bit_length()
+    flat = jnp.pad(cb.astype(jnp.float32), ((0, 0), (0, Lp - L))).reshape(-1)
+    flat = jnp.pad(flat, (0, (-flat.size) % LANES))
+    return flat.reshape(-1, LANES), Lp
+
+
+def _kernel(bs, Hkv, G, W, Dh, L, Lp, scale, softcap, quantized, packed,
             double_buffer,
             table_ref, valid_ref, blkq_ref,
             q_ref, kfp_ref, vfp_ref, kc_ref, vc_ref, kcb_ref, vcb_ref,
             o_ref,
-            k_tile, v_tile, kc_tile, vc_tile, cb_tile, sems):
+            k_tile, v_tile, kc_tile, vc_tile, cb_smem, sems):
     b = pl.program_id(0)
     mb = table_ref.shape[1]
     WG = W * G                    # query rows per kv head ((Hkv, W, G) major)
@@ -109,14 +143,17 @@ def _kernel(bs, Hkv, G, W, Dh, scale, softcap, quantized, packed,
                                       sems.at[s, 1])]
 
     def code_copies(page, s):
-        # ~4 bits/value across the wire: packed codes + two (L,) codebooks
+        # ~4 bits/value across the wire: packed codes + the two 128-lane
+        # codebook rows holding this page's codebooks, those into SMEM
+        # where the lookup reads them as scalars
+        row = page * Lp // LANES
         return [pltpu.make_async_copy(kc_ref.at[page], kc_tile.at[s],
                                       sems.at[s, 0]),
                 pltpu.make_async_copy(vc_ref.at[page], vc_tile.at[s],
                                       sems.at[s, 1]),
-                pltpu.make_async_copy(kcb_ref.at[page], cb_tile.at[s, 0],
+                pltpu.make_async_copy(kcb_ref.at[row], cb_smem.at[2 * s],
                                       sems.at[s, 2]),
-                pltpu.make_async_copy(vcb_ref.at[page], cb_tile.at[s, 1],
+                pltpu.make_async_copy(vcb_ref.at[row], cb_smem.at[2 * s + 1],
                                       sems.at[s, 3])]
 
     def start_page(j, s):
@@ -153,10 +190,13 @@ def _kernel(bs, Hkv, G, W, Dh, scale, softcap, quantized, packed,
             vc = vc_tile[s]
             k_idx = unpack4(kc) if packed else kc.astype(jnp.int32)
             v_idx = unpack4(vc) if packed else vc.astype(jnp.int32)
-            k_tile[s] = jnp.take(cb_tile[s, 0], k_idx.reshape(-1), axis=0
-                                 ).reshape(bs, Hkv, Dh).astype(k_tile.dtype)
-            v_tile[s] = jnp.take(cb_tile[s, 1], v_idx.reshape(-1), axis=0
-                                 ).reshape(bs, Hkv, Dh).astype(v_tile.dtype)
+            off = page * Lp % LANES
+            k_tile[s] = codebook_lookup(
+                k_idx, lambda l: cb_smem[2 * s, off + l], L
+            ).astype(k_tile.dtype)
+            v_tile[s] = codebook_lookup(
+                v_idx, lambda l: cb_smem[2 * s + 1, off + l], L
+            ).astype(v_tile.dtype)
 
         @pl.when(jnp.logical_not(frozen))
         def _():
@@ -243,8 +283,8 @@ def paged_decode_attention(
     q: jax.Array,            # (B, Hq, Dh) queries, or (B, W, Hq, Dh) window
     k_fp: jax.Array,         # (nb, bs, Hkv, Dh) fp page pool
     v_fp: jax.Array,         # (nb, bs, Hkv, Dh)
-    k_codes: jax.Array,      # (nb, bs, Hkv, Dc) packed 4-bit (or u8) codes
-    v_codes: jax.Array,      # (nb, bs, Hkv, Dc)
+    k_codes: jax.Array,      # (nb, bs/2, Hkv, Dh) packed 4-bit codes
+    v_codes: jax.Array,      # (or (nb, bs, Hkv, Dh) u8 when not packed)
     k_cb: jax.Array,         # (nb, L) per-block codebooks, f32
     v_cb: jax.Array,         # (nb, L)
     blk_q: jax.Array,        # (nb,) page is served from codes
@@ -277,7 +317,6 @@ def paged_decode_attention(
     nb, bs, Hkv, _ = k_fp.shape
     assert Hq % Hkv == 0, (Hq, Hkv)
     G = Hq // Hkv
-    Dc = k_codes.shape[-1]
     L = k_cb.shape[1]
     scale = float(1.0 / np.sqrt(Dh))
     # kv-head-major query rows ((Hkv, W, G)) keep the kernel's static
@@ -288,7 +327,7 @@ def paged_decode_attention(
 
     nslots = 2 if double_buffer else 1
     qspec = pl.BlockSpec((1, HqW, Dh), lambda b, *_: (b, 0, 0))
-    hbm = pl.BlockSpec(memory_space=pltpu.ANY)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B,),
@@ -297,23 +336,26 @@ def paged_decode_attention(
         scratch_shapes=[
             pltpu.VMEM((nslots, bs, Hkv, Dh), k_fp.dtype),
             pltpu.VMEM((nslots, bs, Hkv, Dh), v_fp.dtype),
-            pltpu.VMEM((nslots, bs, Hkv, Dc), jnp.uint8),
-            pltpu.VMEM((nslots, bs, Hkv, Dc), jnp.uint8),
-            pltpu.VMEM((nslots, 2, L), jnp.float32),
+            pltpu.VMEM((nslots,) + k_codes.shape[1:], jnp.uint8),
+            pltpu.VMEM((nslots,) + v_codes.shape[1:], jnp.uint8),
+            pltpu.SMEM((2 * nslots, LANES), jnp.float32),
             pltpu.SemaphoreType.DMA((nslots, 4)),
         ],
     )
-    kern = functools.partial(_kernel, bs, Hkv, G, W, Dh, scale, softcap,
-                             quantized, packed, double_buffer)
+    k_rows, Lp = codebook_rows(k_cb)
+    v_rows, _ = codebook_rows(v_cb)
+    kern = functools.partial(_kernel, bs, Hkv, G, W, Dh, L, Lp, scale,
+                             softcap, quantized, packed, double_buffer)
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, HqW, Dh), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(block_table.astype(jnp.int32), kv_valid_len.astype(jnp.int32),
-      blk_q.astype(jnp.int32), qr, k_fp, v_fp, k_codes, v_codes, k_cb, v_cb)
+      blk_q.astype(jnp.int32), qr, k_fp, v_fp, k_codes, v_codes, k_rows,
+      v_rows)
     out = out.reshape(B, Hkv, W, G, Dh).transpose(0, 2, 1, 3, 4)
     out = out.reshape(B, W, Hq, Dh)
     return out if windowed else out[:, 0]

@@ -7,18 +7,14 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 
-def compat_mesh(shape, axes, devices=None):
-    """jax.make_mesh across jax versions: pass axis_types=Auto only where
-    jax.sharding.AxisType exists (older releases are implicitly auto)."""
-    kw = {}
-    at = getattr(jax.sharding, "AxisType", None)
-    if at is not None:
-        kw["axis_types"] = (at.Auto,) * len(axes)
-    if devices is not None:
-        kw["devices"] = devices
-    return jax.make_mesh(shape, axes, **kw)
+def auto_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axes (its default is Explicit): the
+    trainer places arrays with NamedShardings and lets XLA propagate."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -32,12 +28,12 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {n} devices, have {len(devices)}; the dry-run "
             "must set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "before any jax import")
-    return compat_mesh(shape, axes, devices=devices[:n])
+    return auto_mesh(shape, axes, devices=devices[:n])
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over host devices for tests (e.g. 2x4 with device_count=8)."""
-    return compat_mesh((data, model), ("data", "model"))
+    return auto_mesh((data, model), ("data", "model"))
 
 
 def batch_axes(mesh) -> tuple:

@@ -1,9 +1,10 @@
 """Module-level jitted steps for the static (one-shot fixed-batch) serve
 path.
 
-serve.py defers every jax import until after main() has set XLA_FLAGS, so
-its jits cannot live at its module scope — they live here instead
-(imported lazily by ``_run_static``), keeping the shared-jit convention:
+serve.py imports jax only inside its run functions (argument errors and
+``--help`` never load it), so its jits cannot live at its module scope —
+they live here instead (imported lazily by ``_run_static``), keeping the
+shared-jit convention:
 one compile cache per step shape, keyed on the hashable cfg, shared by
 every caller instead of re-created per invocation.
 """

@@ -6,9 +6,10 @@ cache exactly like ``transformer.init_lm_cache``):
 
   k_fp/v_fp     (nb, bs, Hkv, Dh)  fp pages — the write-hot pool; every
                 token lands here first.
-  k_codes/...   (nb, bs, Hkv, Dc)  uint8 codes for quantized pages
-                (Dc = Dh/2 when two 4-bit codes pack per byte, split-half
-                layout — see kernels.paged_attention.pack4).
+  k_codes/...   (nb, bs/2, Hkv, Dh) uint8 codes for quantized pages,
+                two 4-bit codes per byte split-half along the token axis
+                (see kernels.paged_attention.pack4); (nb, bs, Hkv, Dh)
+                unpacked when codebooks exceed 16 values.
   k_cb/v_cb     (nb, L) f32        per-block codebooks from the paper's
                 solvers (kmeans_ls / tv via repro.core.quantize).
   blk_q         (nb,) bool         page i is frozen: codes are
@@ -237,9 +238,9 @@ class PrefixIndex:
 
 
 def _pack4(codes: np.ndarray) -> np.ndarray:
-    """Host-side pack4 (same split-half layout as kernels.pack4)."""
-    D = codes.shape[-1]
-    lo, hi = codes[..., : D // 2], codes[..., D // 2:]
+    """Host-side pack4 (same split-half token-axis layout as kernels.pack4)."""
+    bs = codes.shape[-3]
+    lo, hi = codes[..., : bs // 2, :, :], codes[..., bs // 2:, :, :]
     return (lo | (hi << 4)).astype(np.uint8)
 
 
@@ -389,9 +390,11 @@ def init_paged_layer(cfg, *, num_blocks, block_size, batch, max_blocks,
                      fused=False, fused_window=1) -> PagedKVCache:
     Hkv, Dh = cfg.n_kv_heads, cfg.head_dim
     packed = quantized and num_values <= 16
-    assert Dh % 2 == 0 or not packed
-    Dc = Dh // 2 if packed else Dh
-    cshape = (num_blocks, block_size, Hkv, Dc) if quantized else (1, 1, 1, 1)
+    assert block_size % 2 == 0 or not packed, (
+        f"4-bit pages pack along the token axis: block size {block_size} "
+        f"must be even")
+    rows = block_size // 2 if packed else block_size
+    cshape = (num_blocks, rows, Hkv, Dh) if quantized else (1, 1, 1, 1)
     cbshape = (num_blocks, num_values) if quantized else (1, 1)
     return PagedKVCache(
         k_fp=jnp.zeros((num_blocks, block_size, Hkv, Dh), dtype),
@@ -587,7 +590,7 @@ def freeze_blocks(tree, block_ids, spec=None, *, method=None,
 def _solve_leaf_pages(leaf: PagedKVCache, jb, *, spec: QuantSpec):
     """Gather pages ``jb`` from one layer leaf and solve their codebooks as
     a single jitted computation (one async dispatch per layer), keyed on
-    the hashable spec. Returns (codes (2, G?, P, bs, Hkv, Dc),
+    the hashable spec. Returns (codes (2, G?, P, bs/2, Hkv, Dh) packed,
     cb (2, G?, P, L)) — k stacked over v on the leading axis — without
     touching the leaf."""
     solve = quant_registry.device_batch_solve(spec.method)

@@ -74,7 +74,7 @@ class PagePayload:
     per layer leaf (G? = stacked group axis when present):
 
       full    (2, G?, n_full, bs, Hkv, Dh)   fp full pages       [fp]
-      frozen  ((2, G?, n_full, bs, Hkv, Dc), (2, G?, n_full, L)) [frozen]
+      frozen  ((2, G?, n_full, bs/2, Hkv, Dh), (2, G?, n_full, L)) [frozen]
       tail    (2, G?, tail_rows, Hkv, Dh)    partial-page rows   [fp+frozen]
 
     "resident" payloads split the full pages between ``full`` (unfrozen,

@@ -38,9 +38,9 @@ def test_analyzer_multiplies_scan_trip_counts():
 
 @pytest.mark.xfail(strict=False, reason="HLO text emitted by the pinned jax/XLA lacks the scan-trip/collective markers the analyzer parses; passes on newer jax")
 def test_analyzer_counts_collective_bytes():
-    from repro.launch.mesh import compat_mesh
+    from repro.launch.mesh import auto_mesh
 
-    mesh = compat_mesh((8,), ("d",))
+    mesh = auto_mesh((8,), ("d",))
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     def f(x):

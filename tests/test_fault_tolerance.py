@@ -110,18 +110,8 @@ def test_straggler_monitor_flags_slow_steps():
     assert abs(m.ema - 0.1) < 0.02
 
 
-# version gate for the pinned toolchain: explicit-sharding meshes
-# (jax.sharding.AxisType + jax.set_mesh) landed after 0.4.x; the two
-# mesh-scoped tests below need them and fail with AttributeError there
-needs_axis_type = pytest.mark.xfail(
-    not hasattr(jax.sharding, "AxisType"), raises=AttributeError, strict=True,
-    reason=f"jax {jax.__version__} has no jax.sharding.AxisType (needs newer "
-           "jax); pre-existing failure, version-gated on the pinned toolchain")
-
-
 # ------------------------------------------------- gradient compression
 
-@needs_axis_type
 def test_quantized_allreduce_matches_exact_within_tolerance():
     """2-pod compressed all-reduce ~= exact mean; error feedback shrinks the
     bias across repeated applications."""
@@ -150,7 +140,6 @@ def test_quantized_allreduce_matches_exact_within_tolerance():
 
 # ------------------------------------------------- pipeline parallelism
 
-@needs_axis_type
 def test_gpipe_pipeline_matches_sequential():
     from repro.runtime.pipeline import pipeline_forward
 

@@ -115,7 +115,6 @@ def _paged_state(rng, *, nb, bs, Hkv, Dh, L, quantized, packed, frozen_ids=()):
     kfp = jnp.asarray(rng.normal(size=(nb, bs, Hkv, Dh)), jnp.float32)
     vfp = jnp.asarray(rng.normal(size=(nb, bs, Hkv, Dh)), jnp.float32)
     if quantized:
-        Dc = Dh // 2 if packed else Dh
         kcodes = rng.integers(0, L, (nb, bs, Hkv, Dh)).astype(np.uint8)
         vcodes = rng.integers(0, L, (nb, bs, Hkv, Dh)).astype(np.uint8)
         if packed:

@@ -3,6 +3,7 @@ simulation, paged-allocator invariants, paged-cache round-trip vs the dense
 ring cache, and quantized-KV numerics."""
 import dataclasses
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -193,19 +194,20 @@ def test_paged_layer_roundtrip_matches_dense():
                                    np.asarray(k2)[b, 0])
 
 
-@pytest.mark.parametrize("Dh", [6, 8, 32, 62])   # odd and even packed widths
-def test_pack4_roundtrip(Dh):
+@pytest.mark.parametrize("bs", [6, 8, 32, 62])   # odd and even packed rows
+def test_pack4_roundtrip(bs):
     """np pack -> jnp unpack and jnp pack -> jnp unpack are exact inverses
-    for every 4-bit code value, at odd/even packed dims (Dc = Dh/2)."""
+    for every 4-bit code value, at odd/even packed row counts (bs/2 token
+    rows per page; the lane dim keeps its full width)."""
     from repro.kernels import pack4, unpack4
 
     rng = np.random.default_rng(0)
-    codes = rng.integers(0, 16, (5, 4, 2, Dh)).astype(np.uint8)
+    codes = rng.integers(0, 16, (5, bs, 2, 8)).astype(np.uint8)
     # every code value in both nibble positions
-    codes[0, 0, 0, :Dh // 2] = np.arange(Dh // 2) % 16
-    codes[0, 0, 0, Dh // 2:] = 15 - (np.arange(Dh // 2) % 16)
+    codes[0, :bs // 2, 0, 0] = np.arange(bs // 2) % 16
+    codes[0, bs // 2:, 0, 0] = 15 - (np.arange(bs // 2) % 16)
     packed = _pack4(codes)
-    assert packed.shape == (5, 4, 2, Dh // 2)
+    assert packed.shape == (5, bs // 2, 2, 8)
     np.testing.assert_array_equal(np.asarray(_unpack4(jnp.asarray(packed))),
                                   codes)
     # device pack agrees with the host pack bit-for-bit
@@ -230,7 +232,7 @@ def test_all_16_codes_dequantize_exactly():
     codes = (np.arange(bs * Hkv * Dh) % 16).astype(np.uint8).reshape(
         bs, Hkv, Dh)
     cb = np.linspace(-2.0, 2.0, 16).astype(np.float32)
-    packed = jnp.asarray(_pack4(codes))[None]             # (P=1, bs, H, Dc)
+    packed = jnp.asarray(_pack4(codes))[None]          # (P=1, bs/2, H, Dh)
     cbj = jnp.asarray(cb)[None]                           # (P=1, L)
     pending = PendingFreeze(np.asarray([1], np.int32),
                             [(jnp.stack([packed, packed]),
@@ -650,3 +652,51 @@ def test_quantized_kv_iter_l1_fista_device_path(qwen_reduced):
         scale = np.abs(fp.request_logits[i]).max()
         assert d.max() <= 2.5, d.max()
         assert d.max() / scale <= 0.08, (d.max(), scale)
+
+
+# ------------------------------------------------------------- launcher
+
+
+@pytest.mark.parametrize("max_seq_len,completes", [(32, False), (256, True)],
+                         ids=["all_rejected", "served"])
+def test_serve_main_exit_status(max_seq_len, completes):
+    """``serve.main(argv)`` returns the run's summary when requests
+    complete, and exits non-zero when none does (every prompt+gen here
+    overflows a 32-token sequence budget)."""
+    from repro.launch import serve
+
+    argv = ["--reduced", "--engine", "continuous", "--num-requests", "2",
+            "--request-rate", "100", "--prompt-len", "16", "--gen", "24",
+            "--max-seq-len", str(max_seq_len)]
+    if not completes:
+        with pytest.raises(SystemExit) as e:
+            serve.main(argv)
+        assert e.value.code not in (0, None), e.value.code
+        return
+    s = serve.main(argv)
+    assert s["completed"] == 2 and s["rejected"] == 0, s
+
+
+@pytest.mark.parametrize("from_env", [True, False], ids=["env", "fixed"])
+def test_compile_cache_dir(from_env, monkeypatch, tmp_path):
+    """The persistent compile cache follows JAX_COMPILATION_CACHE_DIR when
+    it is set (left to JAX, the config untouched) and otherwise one fixed
+    directory inside the checkout."""
+    from repro.launch.compile_cache import REPO_CACHE_DIR, init_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = init_compile_cache()
+        if from_env:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            assert got == str(REPO_CACHE_DIR)
+            assert jax.config.jax_compilation_cache_dir == got
+            assert REPO_CACHE_DIR.parent == Path(__file__).resolve().parents[1]
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
