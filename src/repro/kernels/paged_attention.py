@@ -353,6 +353,9 @@ def paged_decode_attention(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        # the device trace names the kernel's op by this; the benchmark's
+        # paged_decode_roofline finds its calls by it
+        name="paged_decode_attention",
     )(block_table.astype(jnp.int32), kv_valid_len.astype(jnp.int32),
       blk_q.astype(jnp.int32), qr, k_fp, v_fp, k_codes, v_codes, k_rows,
       v_rows)

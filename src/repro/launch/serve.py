@@ -88,16 +88,17 @@ observability (continuous + disagg engines):
         decisions, prefill dispatch/harvest, decode-step phases
         (dispatch/sync/commit), transfer extract/splice with payload
         bytes, the per-page freeze lifecycle (queued -> dispatched ->
-        installed | dropped | rolled_back) as async spans, and
-        speculative propose/verify/accept/rollback. Load it at
-        https://ui.perfetto.dev (Open trace file) or chrome://tracing.
-        The run prints a reconciliation of trace spans against the
-        engine's freeze/step counters.
+        installed | dropped | rolled_back) as async spans, freeze
+        flush/install spans, and speculative propose/verify/accept/
+        rollback. Load it at https://ui.perfetto.dev (Open trace file) or
+        chrome://tracing. The run prints a reconciliation of trace spans
+        against the engine's freeze/step counters. Under a JAX profiler
+        session the same phases are also profiler annotations named
+        repro:<track>:<phase>.
   --metrics-jsonl PATH  append one JSON metrics snapshot per
         --metrics-interval seconds (streaming counters/gauges/histogram
-        percentiles, windowed over each interval; plus modeled HBM
-        bytes/token roofline gauges). A Prometheus text rendering of the
-        final snapshot lands next to it at PATH + ".prom".
+        percentiles, windowed over each interval). A Prometheus text
+        rendering of the final snapshot lands next to it at PATH + ".prom".
   --metrics-interval S  snapshot cadence in seconds (default 1.0).
 
 overload survival (continuous + disagg engines):
@@ -156,8 +157,8 @@ chunked prefill (--prefill-chunk N, continuous engine):
   the same attention path decode uses; with --attn-impl fused, earlier
   frozen pages cross HBM as packed 4-bit codes + codebooks through the
   double-buffered kernel DMA (the modeled prefill-bytes win on shared
-  frozen context — see the prefill_hbm_bytes_per_token gauge and the
-  prefill rows in BENCH_paged_attention.json). The chunk sequence is
+  frozen context — see the prefill rows in BENCH_paged_attention.json).
+  The chunk sequence is
   logit-identical to single-shot prefill — bitwise on the gather path,
   which the run replays and asserts — and freeze bids are identical
   (queued at attach, after the whole prompt is in cache).
@@ -412,6 +413,7 @@ def _trace_reconcile(tracer, s, speculate: int) -> bool:
     ev = tracer.events
     n_step = count_events(ev, name="decode_step", ph="X")
     n_flush = count_events(ev, name="flush", ph="X")
+    n_inst = count_events(ev, name="install", ph="X")
     nb = count_events(ev, name="page_freeze", ph="b")
     ne = count_events(ev, name="page_freeze", ph="e")
     states: dict = {}
@@ -421,7 +423,8 @@ def _trace_reconcile(tracer, s, speculate: int) -> bool:
             states[st] = states.get(st, 0) + 1
     n_pc = count_events(ev, name="prefill_chunk", ph="X")
     ok = (n_step == s.get("decode_steps", 0)
-          and n_flush == s.get("freeze_dispatches", 0) and nb == ne
+          and n_flush == s.get("freeze_dispatches", 0)
+          and n_inst == s.get("freeze_installs", 0) and nb == ne
           and n_pc == s.get("prefill_chunks", 0))
     if speculate:
         n_acc = count_events(ev, name="accept", ph="i")
@@ -456,7 +459,8 @@ def _trace_reconcile(tracer, s, speculate: int) -> bool:
                     f"(counter {s.get('prefill_chunks', 0)})")
     print(f"[serve] trace: {len(ev)} events | decode_step spans {n_step} "
           f"(counter {s.get('decode_steps', 0)}), freeze flushes {n_flush} "
-          f"(counter {s.get('freeze_dispatches', 0)}), page-freeze spans "
+          f"(counter {s.get('freeze_dispatches', 0)}), installs {n_inst} "
+          f"(counter {s.get('freeze_installs', 0)}), page-freeze spans "
           f"{nb} opened -> {ne} terminal ({state_txt}){off_txt} "
           f"-> {'reconciled' if ok else 'MISMATCH'}")
     return ok

@@ -5,18 +5,16 @@
             default.
 ``stats``   streaming counters/gauges/log-histograms with O(1)-memory
             windowed percentiles.
-``export``  periodic JSONL snapshots, Prometheus text exposition, and
-            host-side modeled roofline gauges for the decode loop.
+``export``  periodic JSONL snapshots and Prometheus text exposition.
 """
-from .export import (MetricsExporter, modeled_decode_hbm_bytes,
-                     prometheus_text)
+from .export import MetricsExporter, prometheus_text
 from .stats import Counter, Gauge, LogHistogram, Registry
-from .trace import (FakeClock, NULL_TRACER, NullTracer, Tracer,
-                    count_events, select_events, tracks_of)
+from .trace import (FakeClock, NULL_TRACER, NullTracer, PREFIX, Tracer,
+                    count_events, phase, select_events, tracks_of)
 
 __all__ = [
     "Counter", "FakeClock", "Gauge", "LogHistogram", "MetricsExporter",
-    "NULL_TRACER", "NullTracer", "Registry", "Tracer", "count_events",
-    "modeled_decode_hbm_bytes", "prometheus_text", "select_events",
+    "NULL_TRACER", "NullTracer", "PREFIX", "Registry", "Tracer",
+    "count_events", "phase", "prometheus_text", "select_events",
     "tracks_of",
 ]

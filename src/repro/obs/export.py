@@ -1,5 +1,5 @@
-"""Metrics export: periodic JSONL snapshots, Prometheus-style text
-exposition, and host-side modeled roofline counters for the decode loop.
+"""Metrics export: periodic JSONL snapshots and Prometheus-style text
+exposition.
 
 ``MetricsExporter`` hangs off an engine run loop: ``maybe_emit()`` is
 called every iteration but only writes when ``interval_s`` elapsed on the
@@ -7,20 +7,12 @@ injected clock (fake clock in tests -> deterministic snapshot cadence).
 Each line is strict JSON (``allow_nan=False``) so downstream ``json.loads``
 round-trips, and windowed percentiles for every log histogram come from a
 counts-delta against the previous emit — no samples stored.
-
-``modeled_decode_hbm_bytes`` is the live-gauge twin of
-``kernels.paged_attention.modeled_hbm_bytes_per_token``: it prices the
-next decode step's KV traffic from host state only (block tables, lens,
-installed-frozen page set, per-page byte model) — no device sync — so the
-run loop can publish bytes/token and a roofline ``t_memory`` every step.
 """
 from __future__ import annotations
 
 import json
 import time
 from typing import Any, Callable
-
-from repro.analysis.roofline import HBM_BW
 
 from .stats import Counter, Gauge, LogHistogram, Registry
 
@@ -147,67 +139,3 @@ def prometheus_text(snapshot: dict, *, prefix: str = "repro") -> str:
             if v.get("mean") is not None:
                 lines.append(f"{base}_sum {v['mean'] * v['n']}")
     return "\n".join(lines) + "\n"
-
-
-# ------------------------------------------------------------- roofline
-
-
-def modeled_decode_hbm_bytes(worker: Any) -> dict | None:
-    """Price the KV traffic of the next decode step for a ``DecodeWorker``
-    from host state only (no device sync).
-
-    gather impl reads every gathered page at fp width:
-        bytes = mb_used * page_fp
-    fused impl reads each live sequence's own pages at their installed
-    width (frozen pages serve codes + codebooks):
-        bytes = sum over active seqs, pages of page[frozen? : fp]
-
-    Returns per-step totals, bytes/token (token = one step of one active
-    sequence), and the roofline ``t_memory`` for the modeled impl; None
-    when no sequence is live.
-    """
-    active = worker.sched.active_slots()
-    if not active:
-        return None
-    bs = worker.block_size
-    pb = worker._pb
-    need = int(worker.lens.max()) + 1
-    mb_used = max(1, -(-need // bs))
-    gather = mb_used * pb["fp"]
-    fused = 0.0
-    for i in active:
-        npages = -(-(int(worker.lens[i]) + 1) // bs)
-        for j in range(npages):
-            blk = int(worker.table[i, j])
-            fused += pb["frozen"] if blk in worker._frozen_pages else pb["fp"]
-    step_bytes = gather if worker.attn_impl == "gather" else fused
-    n_tok = len(active)
-    return {"hbm_bytes_step": float(step_bytes),
-            "hbm_bytes_per_token": float(step_bytes) / n_tok,
-            "hbm_bytes_step_gather": float(gather),
-            "hbm_bytes_step_fused": float(fused),
-            "t_memory_s": float(step_bytes) / HBM_BW}
-
-
-def modeled_prefill_hbm_bytes(pb: dict, blocks, frozen_pages, *,
-                              block_size: int, off: int, chunk: int,
-                              fused: bool) -> dict:
-    """Price one prefill chunk's KV page traffic from host state only —
-    the chunked-prefill twin of ``modeled_decode_hbm_bytes`` (and the live
-    counterpart of ``kernels.modeled_prefill_hbm_bytes_per_token``).
-
-    The chunk at token offset ``off`` attends pages 0..ceil((off+chunk)/bs).
-    fused (kernel) pricing reads each of those pages at its installed width
-    — frozen pages cross as packed codes + codebooks, the shared-context
-    reuse the fused chunked path monetizes; gather pricing expands every
-    table page (the whole worst-case table) at fp width.
-    """
-    npages = max(1, -(-(off + chunk) // block_size))
-    if fused:
-        hbm = sum(pb["frozen"] if int(b) in frozen_pages else pb["fp"]
-                  for b in blocks[:npages])
-    else:
-        hbm = len(blocks) * pb["fp"]
-    return {"hbm_bytes_chunk": float(hbm),
-            "hbm_bytes_per_token": float(hbm) / max(chunk, 1),
-            "t_memory_s": float(hbm) / HBM_BW}

@@ -10,6 +10,18 @@ Design constraints, in order:
       no-ops and returns one shared null context manager from ``span`` —
       nothing allocates, nothing formats, nothing appends.
 
+  profiler clock             ``phase()`` wraps one engine phase (a decode
+      step's dispatch / sync / commit, a freeze flush or install, a
+      prefill, an engine iteration) in a ``jax.profiler.TraceAnnotation``
+      named ``repro:<track>:<name>``. With the JAX profiler on, the
+      annotation lands on the profiler's own host clock, on the
+      ``/host:CPU`` plane beside the device ops, so a device idle gap can
+      be laid against the phase the host was in. ``PREFIX`` is the
+      contract with trace readers. With the profiler off, a phase costs
+      a profiler-state check, two ``perf_counter_ns`` reads and the
+      tracer's ``now``/``complete`` (PERF.md gives its cost measured on
+      the TPU v5e host).
+
   injectable clock           ``Tracer(clock=...)`` takes any zero-arg
       monotonic-seconds callable. Tests inject ``FakeClock`` (a fixed tick
       per call) so a seeded run emits byte-identical trace JSON — the
@@ -29,7 +41,7 @@ Event kinds (Chrome ``ph`` phases):
                 ``t0 = tracer.now(); ...; tracer.complete(...)`` when args
                 (e.g. payload bytes) only exist at the end.
   instant       "i" — a decision point (route, accept, reject).
-  counter       "C" — a per-step gauge (occupancy, modeled HBM bytes).
+  counter       "C" — a per-step gauge (cache occupancy, frozen pages).
   async span    "b"/"n"/"e" with an id — a lifecycle that outlives any one
                 call frame and overlaps its neighbours on the same track.
                 The page-freeze lifecycle (queued -> dispatched ->
@@ -41,6 +53,12 @@ from __future__ import annotations
 import json
 import time
 from typing import Any, Callable, Iterable
+
+from jax.profiler import TraceAnnotation
+
+#: prefix of every profiler annotation ``phase`` writes; trace readers
+#: select the program's phases by it
+PREFIX = "repro:"
 
 
 class FakeClock:
@@ -242,6 +260,55 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(self.to_dict(), f, sort_keys=True,
                       separators=(",", ":"), allow_nan=False)
+
+
+class phase:
+    """One engine phase: a profiler annotation, an in-memory span and,
+    optionally, a counter of its host nanoseconds, in one context manager.
+
+    ``with phase(tracer, "decode/w0", "dispatch", counters,
+    "decode_dispatch_ns", blocks=mb):`` opens the profiler annotation
+    ``repro:decode/w0:dispatch`` (while a profiler session runs); on
+    exit it closes the annotation, adds the phase's ``perf_counter_ns``
+    duration to ``counters[key]`` (when ``key`` is given) and then calls
+    ``tracer.complete(track, name, t0, **args)`` — the call a tracer sees
+    for any span, so a tracer that acts on a span's end (and may raise
+    there) has already seen its time counted. ``args`` known only at the
+    end go into ``p.args`` of ``with ... as p``. A phase left by an
+    exception still counts its time but records no span.
+    """
+
+    __slots__ = ("tr", "track", "name", "counters", "key", "args", "ann",
+                 "t0", "ns")
+
+    def __init__(self, tracer: Any, track: str, name: str,
+                 counters: dict[str, int] | None = None,
+                 key: str | None = None, **args: Any) -> None:
+        self.tr, self.track, self.name = tracer, track, name
+        self.counters, self.key, self.args = counters, key, args
+
+    def __enter__(self) -> "phase":
+        self.t0 = self.tr.now()
+        # with no profiler session the annotation would record nothing:
+        # skip building it (the off path is the serving default)
+        if TraceAnnotation.is_enabled():
+            self.ann = TraceAnnotation(PREFIX + self.track + ":" + self.name)
+            self.ann.__enter__()
+        else:
+            self.ann = None
+        self.ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type: type[BaseException] | None,
+                 *exc: object) -> bool:
+        ns = time.perf_counter_ns() - self.ns
+        if self.ann is not None:
+            self.ann.__exit__(exc_type, *exc)
+        if self.counters is not None and self.key is not None:
+            self.counters[self.key] += ns
+        if exc_type is None:
+            self.tr.complete(self.track, self.name, self.t0, **self.args)
+        return False
 
 
 # ------------------------------------------------------------ inspection

@@ -65,7 +65,7 @@ from collections import deque
 import jax
 
 from repro.core import registry as quant_registry
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import NULL_TRACER, phase
 from repro.quant.serve import fallback_count
 
 from .kv_cache import resolve_kv_spec
@@ -73,6 +73,14 @@ from .metrics import MetricsCollector
 from .overload import OverloadManager, SLOAdmission
 from .scheduler import DisaggRouter, Request, make_requests
 from .workers import DecodeWorker, PrefillWorker
+
+
+def _loop_phase(name: str) -> phase:
+    """A run-loop phase (``engine:iteration``, ``engine:intake``) as a
+    profiler annotation only: how many passes a run makes while it polls
+    in-flight work or waits for arrivals follows the wall clock, and the
+    in-memory trace stays a function of the run's events."""
+    return phase(NULL_TRACER, "engine", name)
 
 
 def _make_overload(metrics, *, offload_pages, preempt, admission, itl_slo_s,
@@ -138,7 +146,6 @@ class ContinuousBatchingEngine:
             speculate=speculate, draft=draft,
             metrics=self.metrics, outputs=self.outputs,
             request_logits=self.request_logits, tracer=self.tracer,
-            roofline_gauges=exporter is not None,
             prefix_cache=prefix_cache)
         # prefill worker inlined into the decode worker's pool: the handoff
         # payload is a no-op "splice" of already-resident block ids
@@ -257,57 +264,64 @@ class ContinuousBatchingEngine:
         now_fn = lambda: time.perf_counter() - t0
         om_work = lambda: om is not None and om.has_work
         while pending or w.sched.has_work or self._chunking or om_work():
-            now = now_fn()
-            while pending and pending[0].arrival_time <= now:
-                self.submit(pending.popleft(), now)
-            if not (w.sched.has_work or self._chunking or om_work()):
-                if not pending:     # everything left was rejected at submit
-                    break
-                nxt = pending[0].arrival_time
-                time.sleep(min(max(nxt - now, 0.0), poll_s) or poll_s)
-                continue
-            if om is not None:
-                # restore-ahead BEFORE admission: an offloaded sequence
-                # re-enters (pages re-installed while its first decode step
-                # is still an iteration away) ahead of every queued arrival
-                om.retry_deferred(w)
-                om.try_restore(w, now_fn)
-            # with the prefix cache on, admission charges each request its
-            # worst case minus the prompt pages already shareable — the
-            # capacity side of sharing (attach splices those pages instead
-            # of allocating them, so the discounted need is what prefill
-            # actually draws from the pool)
-            disc = w.prefix_probe if w.prefix is not None else None
-            for st in w.sched.schedule(w.alloc.num_free, discount=disc):
-                if self.prefill_chunk:
-                    # chunked path: pages allocated now, prompt advances
-                    # one chunk per iteration below; the slot stays out of
-                    # the decode batch until the whole prompt is in cache
-                    self._chunking.append(
-                        (st, self.prefill.start_chunked(st.req, now_fn)))
-                    w.sched.stage(st)
-                else:
-                    # inline prefill straight into the decode worker's
-                    # pool, then the no-op splice attaches the sequence
-                    fin = self.prefill.run_inline(st.req, now_fn)
-                    w.attach(st, fin, now_fn())
-            if self._chunking:
-                # one chunk per iteration (FCFS head), so decode steps for
-                # live sequences interleave between chunks of a long prompt
-                st, state = self._chunking[0]
-                fin = self.prefill.advance_chunk(state, now_fn)
-                if fin is not None:
-                    self._chunking.popleft()
-                    w.sched.activate(st)
-                    w.attach(st, fin, now_fn())
-            if om is not None and self.preempt:
-                om.maybe_preempt(w, now_fn)
-            # one batched (budgeted) solve for the pages the prefills (and
-            # the previous iteration's decode) just filled, then this
-            # iteration's decode step
-            w.step(now_fn)
-            if self.exporter is not None:
-                self.exporter.maybe_emit(self.metrics)
+            # one pass: intake, admission (inline prefills), freezes and
+            # one decode step; each named phase nests inside it
+            with _loop_phase("iteration"):
+                with _loop_phase("intake"):
+                    now = now_fn()
+                    while pending and pending[0].arrival_time <= now:
+                        self.submit(pending.popleft(), now)
+                if not (w.sched.has_work or self._chunking or om_work()):
+                    if not pending:     # everything left was rejected
+                        break
+                    nxt = pending[0].arrival_time
+                    time.sleep(min(max(nxt - now, 0.0), poll_s) or poll_s)
+                    continue
+                if om is not None:
+                    # restore-ahead BEFORE admission: an offloaded sequence
+                    # re-enters (pages re-installed while its first decode step
+                    # is still an iteration away) ahead of every queued arrival
+                    om.retry_deferred(w)
+                    om.try_restore(w, now_fn)
+                # with the prefix cache on, admission charges each request its
+                # worst case minus the prompt pages already shareable — the
+                # capacity side of sharing (attach splices those pages instead
+                # of allocating them, so the discounted need is what prefill
+                # actually draws from the pool)
+                disc = w.prefix_probe if w.prefix is not None else None
+                with _loop_phase("intake"):
+                    admitted = w.sched.schedule(w.alloc.num_free,
+                                                discount=disc)
+                for st in admitted:
+                    if self.prefill_chunk:
+                        # chunked path: pages allocated now, prompt advances
+                        # one chunk per iteration below; the slot stays out of
+                        # the decode batch until the whole prompt is in cache
+                        self._chunking.append(
+                            (st, self.prefill.start_chunked(st.req, now_fn)))
+                        w.sched.stage(st)
+                    else:
+                        # inline prefill straight into the decode worker's
+                        # pool, then the no-op splice attaches the sequence
+                        fin = self.prefill.run_inline(st.req, now_fn)
+                        w.attach(st, fin, now_fn())
+                if self._chunking:
+                    # one chunk per iteration (FCFS head), so decode steps for
+                    # live sequences interleave between chunks of a long prompt
+                    st, state = self._chunking[0]
+                    fin = self.prefill.advance_chunk(state, now_fn)
+                    if fin is not None:
+                        self._chunking.popleft()
+                        w.sched.activate(st)
+                        w.attach(st, fin, now_fn())
+                if om is not None and self.preempt:
+                    om.maybe_preempt(w, now_fn)
+                # one batched (budgeted) solve for the pages the prefills (and
+                # the previous iteration's decode) just filled, then this
+                # iteration's decode step
+                w.step(now_fn)
+                if self.exporter is not None:
+                    self.exporter.maybe_emit(self.metrics)
         w.drain()
         if om is not None:
             # host-tier retirement backstop: an entry still demoted when
@@ -417,7 +431,7 @@ class DisaggEngine:
             record_logits=record_logits, speculate=speculate, draft=draft,
             metrics=self.metrics,
             outputs=self.outputs, request_logits=self.request_logits,
-            tracer=self.tracer, roofline_gauges=exporter is not None)
+            tracer=self.tracer)
             for i in range(decode_workers)]
         self.prefills = [PrefillWorker(
             params, cfg, worker_id=i, block_size=block_size,
@@ -509,47 +523,50 @@ class DisaggEngine:
         t0 = time.perf_counter()
         now_fn = lambda: time.perf_counter() - t0
         while pending or self._has_work:
-            now = now_fn()
-            while pending and pending[0].arrival_time <= now:
-                self.submit(pending.popleft(), now)
-            if not self._has_work:
-                if not pending:     # everything left was rejected at submit
-                    break
-                nxt = pending[0].arrival_time
-                time.sleep(min(max(nxt - now, 0.0), poll_s) or poll_s)
-                continue
-            progressed = False
-            om = self.overload
-            if om is not None:
-                # restore-ahead: offloaded sequences re-enter (onto any
-                # decode worker with capacity — payloads are portable)
-                # before staged prefills or queued arrivals take the space
-                om.retry_deferred(max(self.decode,
-                                      key=lambda d: d.alloc.num_free))
+            with _loop_phase("iteration"):
+                with _loop_phase("intake"):
+                    now = now_fn()
+                    while pending and pending[0].arrival_time <= now:
+                        self.submit(pending.popleft(), now)
+                if not self._has_work:
+                    if not pending:     # everything left was rejected
+                        break
+                    nxt = pending[0].arrival_time
+                    time.sleep(min(max(nxt - now, 0.0), poll_s) or poll_s)
+                    continue
+                progressed = False
+                om = self.overload
+                if om is not None:
+                    # restore-ahead: offloaded sequences re-enter (onto any
+                    # decode worker with capacity — payloads are portable)
+                    # before staged prefills or queued arrivals take the space
+                    om.retry_deferred(max(self.decode,
+                                          key=lambda d: d.alloc.num_free))
+                    for dw in self.decode:
+                        progressed |= bool(om.try_restore(dw, now_fn))
+                self.router.route_prefill(self.prefills)
+                for pw in self.prefills:
+                    for fin in pw.step(now_fn):
+                        self.router.stage(fin)
+                        progressed = True
+                def _place(dw, fin):
+                    st = dw.sched.admit_direct(fin.req)
+                    assert st is not None       # router checked can_accept
+                    dw.attach(st, fin, now_fn())
+                progressed |= bool(self.router.route_decode(self.decode,
+                                                            _place))
+                if om is not None and self.preempt:
+                    for dw in self.decode:
+                        progressed |= om.maybe_preempt(dw, now_fn)
                 for dw in self.decode:
-                    progressed |= bool(om.try_restore(dw, now_fn))
-            self.router.route_prefill(self.prefills)
-            for pw in self.prefills:
-                for fin in pw.step(now_fn):
-                    self.router.stage(fin)
-                    progressed = True
-            def _place(dw, fin):
-                st = dw.sched.admit_direct(fin.req)
-                assert st is not None       # router checked can_accept
-                dw.attach(st, fin, now_fn())
-            progressed |= bool(self.router.route_decode(self.decode, _place))
-            if om is not None and self.preempt:
-                for dw in self.decode:
-                    progressed |= om.maybe_preempt(dw, now_fn)
-            for dw in self.decode:
-                if dw.has_work:
-                    dw.step(now_fn)
-                    progressed = progressed or bool(dw.sched.active)
-            if self.exporter is not None:
-                self.exporter.maybe_emit(self.metrics)
-            if not progressed:
-                # only in-flight prefills to wait on: let the device work
-                time.sleep(poll_s / 4)
+                    if dw.has_work:
+                        dw.step(now_fn)
+                        progressed = progressed or bool(dw.sched.active)
+                if self.exporter is not None:
+                    self.exporter.maybe_emit(self.metrics)
+                if not progressed:
+                    # only in-flight prefills to wait on: let the device work
+                    time.sleep(poll_s / 4)
         for pw in self.prefills:
             assert not pw.busy
         for dw in self.decode:

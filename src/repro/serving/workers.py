@@ -39,9 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import models
-from repro.obs.export import (modeled_decode_hbm_bytes,
-                              modeled_prefill_hbm_bytes)
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import NULL_TRACER, phase
 
 from .kv_cache import (BlockAllocator, PrefixIndex, dispatch_freeze,
                        freeze_blocks, init_paged_cache, install_freeze,
@@ -120,16 +118,11 @@ class DecodeWorker:
                  eos_id: int | None = None, record_logits: bool = False,
                  speculate: int = 0, draft: tuple | None = None,
                  metrics=None, outputs=None, request_logits=None,
-                 tracer=None, roofline_gauges: bool = False,
-                 prefix_cache: bool = False):
+                 tracer=None, prefix_cache: bool = False):
         from .metrics import MetricsCollector
 
         self.worker_id = worker_id
         self.tracer = tracer if tracer is not None else NULL_TRACER
-        # compute per-step modeled HBM gauges even when tracing is off
-        # (a metrics exporter wants them); pure-NullTracer runs skip the
-        # host walk entirely
-        self.roofline_gauges = roofline_gauges
         self._trk_decode = f"decode/w{worker_id}"
         self._trk_freeze = f"freeze/w{worker_id}"
         self._trk_spec = f"spec/w{worker_id}"
@@ -214,7 +207,20 @@ class DecodeWorker:
                          # prefilling, and write-hot tail pages materialized
                          # privately instead of shared (copy-on-write)
                          "prefix_hits": 0, "prefix_shared_pages": 0,
-                         "cow_copies": 0}
+                         "cow_copies": 0,
+                         # host nanoseconds of each engine phase (the
+                         # colocated prefill worker books its prefills
+                         # here too) and the prompt tokens it prefilled
+                         "decode_dispatch_ns": 0, "decode_sync_ns": 0,
+                         "decode_commit_ns": 0, "freeze_flush_ns": 0,
+                         "freeze_install_ns": 0, "prefill_ns": 0,
+                         "prefill_tokens": 0,
+                         # per decode step, summed: pages the step reads
+                         # and how many of them serve 4-bit codes; rows
+                         # the live sequences hold against the rows of
+                         # the pages reserved for them
+                         "kv_pages_read": 0, "kv_pages_read_frozen": 0,
+                         "kv_rows_used": 0, "kv_rows_reserved": 0}
         # radix/hash prefix index over installed-frozen (or, unquantized,
         # sequence-passed) full prompt pages; sequences attach published
         # pages at rc > 1 instead of re-prefilling them
@@ -223,6 +229,8 @@ class DecodeWorker:
         self._freeze_bids: list[int] = []   # queued for the next flush
         self._deferred_seen = 0    # queue suffix already counted deferred
         self._frozen_pages: set[int] = set()   # installed (codes serving)
+        # the same set as a mask over block ids, for per-step read counts
+        self._frozen_mask = np.zeros(self.num_blocks, bool)
         # freeze-lifecycle async spans: page id -> open span id. A span
         # opens when a bid is queued and MUST end in exactly one terminal
         # state — installed / dropped (seq finished first) / rolled_back
@@ -293,54 +301,55 @@ class DecodeWorker:
         pages through ``install_freeze``, directly servable by the fused
         kernel), and the rest fill during decode.
         """
-        req, s = st.req, self.slots[st.slot]
-        payload = fin.payload
-        if payload.mode == "splice":
-            blocks = list(payload.blocks)
-        else:
-            blocks = self.alloc.alloc(self.sched.blocks_for(req))
-            self.tree = splice_payload(self.tree, payload, blocks,
-                                       tracer=self.tracer)
-            self.counters["migrated_seqs"] += 1
-            self.counters["migrated_pages"] += payload.n_pages
-            self.counters["migrate_bytes"] += payload.nbytes
-            self.counters["migrate_fp_equiv_bytes"] += payload.fp_equiv_bytes
-        P = req.prompt_len
-        s.rid, s.blocks = req.id, blocks
-        s.out, s.logits = [fin.first_token], []
-        s.last_token = fin.first_token
-        s.rng, s.temperature, s.top_k = fin.rng, req.temperature, req.top_k
-        if self.record_logits and fin.last_logits is not None:
-            s.logits.append(fin.last_logits)
-        self.table[st.slot] = 0
-        self.table[st.slot, :len(blocks)] = blocks
-        self.lens[st.slot] = P
-        st.length, st.generated = P, 1
-        # a fresh attach is the coldest possible preemption candidate at
-        # the current step — seed the LRU signal so pick_victim can see it
-        # before its first decode step
-        self.last_attended[st.slot] = self.counters["decode_steps"]
-        if payload.mode == "frozen" and payload.n_full:
-            # pages landed as codes+codebooks: already frozen, never queue
-            # them for a second solve
-            s.frozen_upto = payload.n_full
-            self._frozen_pages.update(int(b)
-                                      for b in blocks[:payload.n_full])
-        else:
-            # a shared prefix splices installed-frozen pages: they start
-            # the frozen watermark, so they are never queued for a second
-            # solve (unquantized pools share exact-fp pages; the watermark
-            # stays 0 because nothing ever freezes)
-            s.frozen_upto = (payload.shared_pages
-                             if self.kv_spec is not None else 0)
-            self._queue_freeze(st.slot)
-        if self.draft is not None:
-            # the draft prefills the same prompt on its own pool (cheap:
-            # the draft config is the reduced one) and mirrors this slot
-            self.draft.attach(st.slot, req.prompt, len(blocks))
-        self._publish_prefixes()
-        if st.done or fin.first_token == self.eos_id:
-            self._finish(st, now)
+        with phase(self.tracer, self._trk_decode, "attach", rid=st.req.id):
+            req, s = st.req, self.slots[st.slot]
+            payload = fin.payload
+            if payload.mode == "splice":
+                blocks = list(payload.blocks)
+            else:
+                blocks = self.alloc.alloc(self.sched.blocks_for(req))
+                self.tree = splice_payload(self.tree, payload, blocks,
+                                           tracer=self.tracer)
+                self.counters["migrated_seqs"] += 1
+                self.counters["migrated_pages"] += payload.n_pages
+                self.counters["migrate_bytes"] += payload.nbytes
+                self.counters["migrate_fp_equiv_bytes"] += (
+                    payload.fp_equiv_bytes)
+            P = req.prompt_len
+            s.rid, s.blocks = req.id, blocks
+            s.out, s.logits = [fin.first_token], []
+            s.last_token = fin.first_token
+            s.rng, s.temperature, s.top_k = fin.rng, req.temperature, req.top_k
+            if self.record_logits and fin.last_logits is not None:
+                s.logits.append(fin.last_logits)
+            self.table[st.slot] = 0
+            self.table[st.slot, :len(blocks)] = blocks
+            self.lens[st.slot] = P
+            st.length, st.generated = P, 1
+            # a fresh attach is the coldest possible preemption candidate
+            # at the current step — seed the LRU signal so pick_victim can
+            # see it before its first decode step
+            self.last_attended[st.slot] = self.counters["decode_steps"]
+            if payload.mode == "frozen" and payload.n_full:
+                # pages landed as codes+codebooks: already frozen, never queue
+                # them for a second solve
+                s.frozen_upto = payload.n_full
+                self._mark_frozen(blocks[:payload.n_full])
+            else:
+                # a shared prefix splices installed-frozen pages: they start
+                # the frozen watermark, so they are never queued for a second
+                # solve (unquantized pools share exact-fp pages; the watermark
+                # stays 0 because nothing ever freezes)
+                s.frozen_upto = (payload.shared_pages
+                                 if self.kv_spec is not None else 0)
+                self._queue_freeze(st.slot)
+            if self.draft is not None:
+                # the draft prefills the same prompt on its own pool (cheap:
+                # the draft config is the reduced one) and mirrors this slot
+                self.draft.attach(st.slot, req.prompt, len(blocks))
+            self._publish_prefixes()
+            if st.done or fin.first_token == self.eos_id:
+                self._finish(st, now)
 
     # ------------------------------------------------------ prefix sharing
 
@@ -408,60 +417,79 @@ class DecodeWorker:
         active = self.sched.active_slots()
         if not active:
             return
-        tr = self.tracer
-        t_step = tr.now()
-        self.counters["decode_steps"] += 1
-        self.counters["seq_decode_steps"] += len(active)
-        self._poll_freezes()
-        toks = np.zeros((len(self.slots), 1), np.int32)
-        for i in active:
-            toks[i, 0] = self.slots[i].last_token
-        # gather only the blocks the longest live sequence needs this step
-        # (idle slots sit at length 0); retraces are bounded by max_blocks
-        need = int(self.lens.max()) + 1
-        mb_used = max(1, -(-need // self.block_size))
-        self.counters["max_gather_blocks"] = max(
-            self.counters["max_gather_blocks"], mb_used)
-        t0 = tr.now()
-        tree = with_tables(self.tree, self.table[:, :mb_used], self.lens)
-        lens = jnp.asarray(self.lens)
-        logits, new = self._decode_fn(self.params, jnp.asarray(toks), tree,
-                                      lens)
-        self.tree = merge_pools(self.tree, new)
-        tr.complete(self._trk_decode, "dispatch", t0, blocks=mb_used)
-        t0 = tr.now()
-        # lint: sync(intentional step-end token sync for the scheduler)
-        nxt = np.asarray(jnp.argmax(logits[:, -1], -1))
-        sampling = any(self.slots[i].temperature > 0.0 for i in active)
-        # lint: sync(host sampling/record path needs this step's logit row)
-        rows = (np.asarray(logits[:, -1])
-                if self.record_logits or sampling else None)
-        tr.complete(self._trk_decode, "sync", t0)
-        t0 = tr.now()
-        now = now_fn()
-        finished = []
-        for i in active:
-            st = self.sched.active[i]
-            s = self.slots[i]
-            self.last_attended[i] = self.counters["decode_steps"]
-            self.lens[i] += 1
-            st.length += 1
-            st.generated += 1
-            s.last_token = (sample_token(rows[i], temperature=s.temperature,
-                                         top_k=s.top_k, rng=s.rng)
-                            if s.temperature > 0.0 else int(nxt[i]))
-            s.out.append(s.last_token)
-            if self.record_logits:
-                s.logits.append(rows[i])
-            self.metrics.token(st.req.id, now)
-            self._queue_freeze(i)
-            if st.done or s.last_token == self.eos_id:
-                finished.append(st)
-        for st in finished:
-            self._finish(st, now)
-        tr.complete(self._trk_decode, "commit", t0, finished=len(finished))
-        tr.complete(self._trk_decode, "decode_step", t_step,
-                    step=self.counters["decode_steps"], active=len(active))
+        tr, c, trk = self.tracer, self.counters, self._trk_decode
+        c["decode_steps"] += 1
+        c["seq_decode_steps"] += len(active)
+        with phase(tr, trk, "decode_step", step=c["decode_steps"],
+                   active=len(active)):
+            self._poll_freezes()
+            toks = np.zeros((len(self.slots), 1), np.int32)
+            for i in active:
+                toks[i, 0] = self.slots[i].last_token
+            # gather only the blocks the longest live sequence needs this
+            # step (idle slots sit at length 0); retraces are bounded by
+            # max_blocks
+            need = int(self.lens.max()) + 1
+            mb_used = max(1, -(-need // self.block_size))
+            c["max_gather_blocks"] = max(c["max_gather_blocks"], mb_used)
+            with phase(tr, trk, "dispatch", c, "decode_dispatch_ns",
+                       blocks=mb_used):
+                self._count_reads(active, mb_used, 1)
+                tree = with_tables(self.tree, self.table[:, :mb_used],
+                                   self.lens)
+                lens = jnp.asarray(self.lens)
+                logits, new = self._decode_fn(self.params, jnp.asarray(toks),
+                                              tree, lens)
+                self.tree = merge_pools(self.tree, new)
+            with phase(tr, trk, "sync", c, "decode_sync_ns"):
+                # lint: sync(intentional step-end token sync for the scheduler)
+                nxt = np.asarray(jnp.argmax(logits[:, -1], -1))
+                sampling = any(self.slots[i].temperature > 0.0
+                               for i in active)
+                # lint: sync(host sampling/record path needs this step's row)
+                rows = (np.asarray(logits[:, -1])
+                        if self.record_logits or sampling else None)
+            with phase(tr, trk, "commit", c, "decode_commit_ns") as p:
+                now = now_fn()
+                finished = []
+                for i in active:
+                    st = self.sched.active[i]
+                    s = self.slots[i]
+                    self.last_attended[i] = c["decode_steps"]
+                    self.lens[i] += 1
+                    st.length += 1
+                    st.generated += 1
+                    s.last_token = (sample_token(rows[i],
+                                                 temperature=s.temperature,
+                                                 top_k=s.top_k, rng=s.rng)
+                                    if s.temperature > 0.0 else int(nxt[i]))
+                    s.out.append(s.last_token)
+                    if self.record_logits:
+                        s.logits.append(rows[i])
+                    self.metrics.token(st.req.id, now)
+                    self._queue_freeze(i)
+                    if st.done or s.last_token == self.eos_id:
+                        finished.append(st)
+                for st in finished:
+                    self._finish(st, now)
+                p.args["finished"] = len(finished)
+
+    def _count_reads(self, active: list[int], mb_used: int, W: int) -> None:
+        """Count what this step reads, from the table and lengths it is
+        dispatched with: each live slot's pages up to its last written row
+        (``ceil((len + W) / bs)`` for a W-token step), the installed-frozen
+        ones among them, and its held rows against its reserved pages'."""
+        bs, c = self.block_size, self.counters
+        idx = np.asarray(active)
+        lens = self.lens[idx]
+        pages = (lens + W - 1 + bs) // bs
+        frozen = self._frozen_mask[self.table[idx, :mb_used]]
+        frozen &= np.arange(mb_used) < pages[:, None]
+        c["kv_pages_read"] += int(pages.sum())
+        c["kv_pages_read_frozen"] += int(np.count_nonzero(frozen))
+        c["kv_rows_used"] += int(lens.sum())
+        c["kv_rows_reserved"] += bs * sum(len(self.slots[i].blocks)
+                                          for i in active)
 
     # ------------------------------------------------------- speculative
 
@@ -484,88 +512,96 @@ class DecodeWorker:
         active = self.sched.active_slots()
         if not active:
             return
-        tr = self.tracer
-        t_step = tr.now()
+        tr, c, trk = self.tracer, self.counters, self._trk_decode
         k = self.speculate
         W = k + 1
-        self.counters["decode_steps"] += 1
-        self.counters["seq_decode_steps"] += len(active)
-        self._poll_freezes()
-        t0 = tr.now()
-        proposals = self.draft.propose(active, self.slots, k)
-        tr.complete(self._trk_spec, "propose", t0, k=k, active=len(active))
-        toks = np.zeros((len(self.slots), W), np.int32)
-        for i in active:
-            toks[i, 0] = self.slots[i].last_token
-            toks[i, 1:] = proposals[i]
-        # gather only the blocks the longest live sequence's window needs
-        need = int(self.lens.max()) + W
-        mb_used = max(1, -(-need // self.block_size))
-        self.counters["max_gather_blocks"] = max(
-            self.counters["max_gather_blocks"], mb_used)
-        t0 = tr.now()
-        tree = with_tables(self.tree, self.table[:, :mb_used], self.lens)
-        logits, new = self._verify_fn(self.params, jnp.asarray(toks), tree,
-                                      jnp.asarray(self.lens))
-        self.tree = merge_pools(self.tree, new)
-        # lint: sync(step-end verify sync: acceptance logic runs on host)
-        preds = np.asarray(jnp.argmax(logits, -1))            # (B, W)
-        tr.complete(self._trk_spec, "verify", t0, window=W,
-                    active=len(active), blocks=mb_used)
-        sampling = any(self.slots[i].temperature > 0.0 for i in active)
-        assert not sampling, (
-            "speculative decoding serves the greedy verification path; "
-            "sampled requests need the non-speculative engine")
-        # lint: sync(verification-only logit capture, off in production)
-        rows = np.asarray(logits) if self.record_logits else None
-        now = now_fn()
-        finished = []
-        for i in active:
-            st = self.sched.active[i]
-            s = self.slots[i]
-            self.last_attended[i] = self.counters["decode_steps"]
-            L = int(self.lens[i])
-            # optimistic: all W rows written; advance + queue freezes as if
-            # every draft were accepted, then roll back to the watermark
-            self.lens[i] = L + W
-            self._queue_freeze(i)
-            n_acc = 0
-            while n_acc < k and proposals[i][n_acc] == int(preds[i, n_acc]):
-                n_acc += 1
-            # row j of the verify logits is the target's next-token
-            # distribution after [ctx, last, d1..dj]: accepted drafts are
-            # emitted verbatim, and row n_acc supplies the correction (or
-            # the bonus token when every draft survived) — uniformly its
-            # argmax
-            emitted = [int(t) for t in proposals[i][:n_acc]]
-            emitted.append(int(preds[i, n_acc]))
-            emitted = emitted[:st.req.max_new_tokens - st.generated]
-            if self.eos_id is not None and self.eos_id in emitted:
-                emitted = emitted[:emitted.index(self.eos_id) + 1]
-            a = len(emitted)
-            self.metrics.spec_step(k, min(n_acc, a), a < W)
-            tr.instant(self._trk_spec, "accept", slot=i, rid=st.req.id,
-                       proposed=k, accepted=min(n_acc, a), emitted=a)
-            if a < W:
-                tr.instant(self._trk_spec, "rollback", slot=i,
-                           rid=st.req.id, to_len=L + a)
-            self._rollback_slot(i, L + a)
-            st.length = L + a
-            st.generated += a
-            for j, t in enumerate(emitted):
-                s.out.append(t)
-                if self.record_logits:
-                    s.logits.append(rows[i, j])
-                self.metrics.token(st.req.id, now)
-            s.last_token = emitted[-1]
-            self.draft.sync(i, L + a)
-            if st.done or s.last_token == self.eos_id:
-                finished.append(st)
-        for st in finished:
-            self._finish(st, now)
-        tr.complete(self._trk_decode, "decode_step", t_step,
-                    step=self.counters["decode_steps"], active=len(active),
-                    window=W)
+        c["decode_steps"] += 1
+        c["seq_decode_steps"] += len(active)
+        with phase(tr, trk, "decode_step", step=c["decode_steps"],
+                   active=len(active), window=W):
+            self._poll_freezes()
+            with phase(tr, self._trk_spec, "propose", k=k,
+                       active=len(active)):
+                proposals = self.draft.propose(active, self.slots, k)
+            toks = np.zeros((len(self.slots), W), np.int32)
+            for i in active:
+                toks[i, 0] = self.slots[i].last_token
+                toks[i, 1:] = proposals[i]
+            # gather only the blocks the longest live sequence's window
+            # needs
+            need = int(self.lens.max()) + W
+            mb_used = max(1, -(-need // self.block_size))
+            c["max_gather_blocks"] = max(c["max_gather_blocks"], mb_used)
+            with phase(tr, self._trk_spec, "verify", window=W,
+                       active=len(active), blocks=mb_used):
+                with phase(tr, trk, "dispatch", c, "decode_dispatch_ns",
+                           blocks=mb_used):
+                    self._count_reads(active, mb_used, W)
+                    tree = with_tables(self.tree, self.table[:, :mb_used],
+                                       self.lens)
+                    logits, new = self._verify_fn(
+                        self.params, jnp.asarray(toks), tree,
+                        jnp.asarray(self.lens))
+                    self.tree = merge_pools(self.tree, new)
+                with phase(tr, trk, "sync", c, "decode_sync_ns"):
+                    # lint: sync(step-end verify sync: acceptance runs on host)
+                    preds = np.asarray(jnp.argmax(logits, -1))    # (B, W)
+            sampling = any(self.slots[i].temperature > 0.0 for i in active)
+            assert not sampling, (
+                "speculative decoding serves the greedy verification path; "
+                "sampled requests need the non-speculative engine")
+            with phase(tr, trk, "commit", c, "decode_commit_ns") as p:
+                # lint: sync(verification-only logit capture, off in serving)
+                rows = np.asarray(logits) if self.record_logits else None
+                now = now_fn()
+                finished = []
+                for i in active:
+                    st = self.sched.active[i]
+                    s = self.slots[i]
+                    self.last_attended[i] = c["decode_steps"]
+                    L = int(self.lens[i])
+                    # optimistic: all W rows written; advance + queue
+                    # freezes as if every draft were accepted, then roll
+                    # back to the watermark
+                    self.lens[i] = L + W
+                    self._queue_freeze(i)
+                    n_acc = 0
+                    while (n_acc < k
+                           and proposals[i][n_acc] == int(preds[i, n_acc])):
+                        n_acc += 1
+                    # row j of the verify logits is the target's next-token
+                    # distribution after [ctx, last, d1..dj]: accepted
+                    # drafts are emitted verbatim, and row n_acc supplies
+                    # the correction (or the bonus token when every draft
+                    # survived) — uniformly its argmax
+                    emitted = [int(t) for t in proposals[i][:n_acc]]
+                    emitted.append(int(preds[i, n_acc]))
+                    emitted = emitted[:st.req.max_new_tokens - st.generated]
+                    if self.eos_id is not None and self.eos_id in emitted:
+                        emitted = emitted[:emitted.index(self.eos_id) + 1]
+                    a = len(emitted)
+                    self.metrics.spec_step(k, min(n_acc, a), a < W)
+                    tr.instant(self._trk_spec, "accept", slot=i,
+                               rid=st.req.id, proposed=k,
+                               accepted=min(n_acc, a), emitted=a)
+                    if a < W:
+                        tr.instant(self._trk_spec, "rollback", slot=i,
+                                   rid=st.req.id, to_len=L + a)
+                    self._rollback_slot(i, L + a)
+                    st.length = L + a
+                    st.generated += a
+                    for j, t in enumerate(emitted):
+                        s.out.append(t)
+                        if self.record_logits:
+                            s.logits.append(rows[i, j])
+                        self.metrics.token(st.req.id, now)
+                    s.last_token = emitted[-1]
+                    self.draft.sync(i, L + a)
+                    if st.done or s.last_token == self.eos_id:
+                        finished.append(st)
+                for st in finished:
+                    self._finish(st, now)
+                p.args["finished"] = len(finished)
 
     def _rollback_slot(self, slot: int, new_len: int) -> None:
         """Shrink a slot to its accepted watermark ``new_len``: un-queue
@@ -602,35 +638,44 @@ class DecodeWorker:
         decode step. drain=True blocks on the remainder (end of run)."""
         still = []
         installed_any = False
+        tr, c = self.tracer, self.counters
         for step0, pending in self._pending_freezes:
             if drain and not pending.is_ready():
                 # lint: sync(drain-only: end-of-run flush blocks by design)
                 jax.block_until_ready(pending.markers())
             if pending.is_ready():
-                self.tree = install_freeze(self.tree, pending)
-                kept = pending.kept_pages()
-                self._frozen_pages.update(kept)
-                installed_any = True
-                self.counters["freeze_installs"] += 1
-                self.counters["freeze_overlap_steps"] += (
-                    self.counters["decode_steps"] - step0)
-                tr = self.tracer
-                if tr.enabled:
-                    tr.instant(self._trk_freeze, "install", pages=len(kept),
-                               wait_steps=self.counters["decode_steps"]
-                               - step0)
-                    for b in kept:
-                        sid = self._page_spans.pop(b, None)
-                        if sid is not None:
-                            tr.async_end(self._trk_freeze, "page_freeze",
-                                         sid, state="installed", page=b)
+                wait = c["decode_steps"] - step0
+                with phase(tr, self._trk_freeze, "install", c,
+                           "freeze_install_ns", wait_steps=wait) as p:
+                    self.tree = install_freeze(self.tree, pending)
+                    kept = pending.kept_pages()
+                    self._mark_frozen(kept)
+                    installed_any = True
+                    c["freeze_installs"] += 1
+                    c["freeze_overlap_steps"] += wait
+                    p.args["pages"] = len(kept)
+                    if tr.enabled:
+                        for b in kept:
+                            sid = self._page_spans.pop(b, None)
+                            if sid is not None:
+                                tr.async_end(self._trk_freeze, "page_freeze",
+                                             sid, state="installed", page=b)
             else:
-                self.counters["freeze_inflight_steps"] += 1
+                c["freeze_inflight_steps"] += 1
                 still.append((step0, pending))
         self._pending_freezes = still
         if installed_any:
             # freshly installed pages just became shareable
             self._publish_prefixes()
+
+    def _mark_frozen(self, blocks) -> None:
+        blocks = [int(b) for b in blocks]
+        self._frozen_pages.update(blocks)
+        self._frozen_mask[blocks] = True
+
+    def _unmark_frozen(self, blocks: set) -> None:
+        self._frozen_pages -= blocks
+        self._frozen_mask[list(blocks)] = False
 
     def _queue_freeze(self, slot: int) -> None:
         """Queue this sequence's just-filled pages for quantization; the
@@ -671,55 +716,58 @@ class DecodeWorker:
         if not self._freeze_bids:
             return
         tr = self.tracer
-        t0 = tr.now()
         take = min(len(self._freeze_bids), self.freeze_page_budget)
-        bids, self._freeze_bids = (self._freeze_bids[:take],
-                                   self._freeze_bids[take:])
-        if tr.enabled:
-            for b in bids:
-                sid = self._page_spans.get(b)
-                if sid is not None:
-                    tr.async_instant(self._trk_freeze, "page_freeze", sid,
-                                     state="dispatched")
-        # count each page's deferral once: the flush consumed ``take``
-        # pages off the queue front (the oldest, hence any already-counted
-        # ones first), so shrink the counted watermark by that before
-        # counting what now remains beyond it as newly deferred
-        self._deferred_seen = max(self._deferred_seen - take, 0)
-        newly = len(self._freeze_bids) - self._deferred_seen
-        if newly > 0:
-            self.counters["freeze_deferred_pages"] += newly
-        self._deferred_seen = len(self._freeze_bids)
-        if self.kv_spec.device_capable:
-            # pad to a power-of-two page count (repeating one page is a
-            # no-op at install) so the jitted solver compiles a handful of
-            # shapes instead of one per distinct flush size; the host
-            # fallback solves per page, where a duplicate is pure waste
-            bucket = 1 << (len(bids) - 1).bit_length()
-            bids = bids + [bids[-1]] * (bucket - len(bids))
-        if self.freeze_async:
-            pending = dispatch_freeze(self.tree, bids, self.kv_spec)
-            self._pending_freezes.append(
-                (self.counters["decode_steps"], pending))
-            self.counters["freeze_pending_max"] = max(
-                self.counters["freeze_pending_max"],
-                len(self._pending_freezes))
-        else:
-            self.tree = freeze_blocks(self.tree, bids, self.kv_spec,
-                                      stats=self.counters)
-            self._frozen_pages.update(bids)
-            self._publish_prefixes()    # synchronous install: shareable now
-            self.counters["freeze_installs"] += 1
+        with phase(tr, self._trk_freeze, "flush", self.counters,
+                   "freeze_flush_ns", pages=take,
+                   mode="async" if self.freeze_async else "sync"):
+            bids, self._freeze_bids = (self._freeze_bids[:take],
+                                       self._freeze_bids[take:])
             if tr.enabled:
-                # synchronous install: the lifecycle terminates here
-                for b in sorted(set(bids)):
-                    sid = self._page_spans.pop(b, None)
+                for b in bids:
+                    sid = self._page_spans.get(b)
                     if sid is not None:
-                        tr.async_end(self._trk_freeze, "page_freeze", sid,
-                                     state="installed", page=b)
-        self.counters["freeze_dispatches"] += 1
-        tr.complete(self._trk_freeze, "flush", t0, pages=take,
-                    mode="async" if self.freeze_async else "sync")
+                        tr.async_instant(self._trk_freeze, "page_freeze", sid,
+                                         state="dispatched")
+            # count each page's deferral once: the flush consumed ``take``
+            # pages off the queue front (the oldest, hence any already-counted
+            # ones first), so shrink the counted watermark by that before
+            # counting what now remains beyond it as newly deferred
+            self._deferred_seen = max(self._deferred_seen - take, 0)
+            newly = len(self._freeze_bids) - self._deferred_seen
+            if newly > 0:
+                self.counters["freeze_deferred_pages"] += newly
+            self._deferred_seen = len(self._freeze_bids)
+            if self.kv_spec.device_capable:
+                # pad to a power-of-two page count (repeating one page is a
+                # no-op at install) so the jitted solver compiles a handful of
+                # shapes instead of one per distinct flush size; the host
+                # fallback solves per page, where a duplicate is pure waste
+                bucket = 1 << (len(bids) - 1).bit_length()
+                bids = bids + [bids[-1]] * (bucket - len(bids))
+            if self.freeze_async:
+                pending = dispatch_freeze(self.tree, bids, self.kv_spec)
+                self._pending_freezes.append(
+                    (self.counters["decode_steps"], pending))
+                self.counters["freeze_pending_max"] = max(
+                    self.counters["freeze_pending_max"],
+                    len(self._pending_freezes))
+            else:
+                # synchronous solve + install, inside the flush's own time
+                with phase(tr, self._trk_freeze, "install",
+                           pages=len(set(bids)), wait_steps=0):
+                    self.tree = freeze_blocks(self.tree, bids, self.kv_spec,
+                                              stats=self.counters)
+                    self._mark_frozen(bids)
+                self._publish_prefixes()    # installed: shareable now
+                self.counters["freeze_installs"] += 1
+                if tr.enabled:
+                    # synchronous install: the lifecycle terminates here
+                    for b in sorted(set(bids)):
+                        sid = self._page_spans.pop(b, None)
+                        if sid is not None:
+                            tr.async_end(self._trk_freeze, "page_freeze", sid,
+                                         state="installed", page=b)
+            self.counters["freeze_dispatches"] += 1
 
     # ------------------------------------------------------------ teardown
 
@@ -751,7 +799,7 @@ class DecodeWorker:
         self._freeze_bids = [b for b in self._freeze_bids
                              if b not in released]
         self._deferred_seen = min(self._deferred_seen, len(self._freeze_bids))
-        self._frozen_pages -= released
+        self._unmark_frozen(released)
         for _, pending in self._pending_freezes:
             pending.drop(released)
         self.tree = thaw_blocks(self.tree, released)
@@ -862,7 +910,7 @@ class DecodeWorker:
         self._freeze_bids = [b for b in self._freeze_bids
                              if b not in released]
         self._deferred_seen = min(self._deferred_seen, len(self._freeze_bids))
-        self._frozen_pages -= released
+        self._unmark_frozen(released)
         for _, pending in self._pending_freezes:
             pending.drop(released)
         self.tree = thaw_blocks(self.tree, released)
@@ -926,8 +974,7 @@ class DecodeWorker:
         self.lens[st.slot] = entry.n_tokens
         st.length, st.generated = entry.n_tokens, entry.generated
         self.last_attended[st.slot] = self.counters["decode_steps"]
-        self._frozen_pages.update(int(blocks[m + j])
-                                  for j in entry.frozen_idx)
+        self._mark_frozen(blocks[m + j] for j in entry.frozen_idx)
         # frozen_upto is the maximal frozen PREFIX; installs land in queue
         # order so the frozen set is a prefix in practice. If it ever
         # weren't, _queue_freeze would re-solve an already-frozen page —
@@ -990,7 +1037,7 @@ class DecodeWorker:
             # frozen from page 0, so they must be installed before decoding
             self.tree = freeze_blocks(self.tree, blocks, self.kv_spec,
                                       stats=self.counters)
-            self._frozen_pages.update(blocks)
+            self._mark_frozen(blocks)
         return blocks
 
     def drain(self) -> None:
@@ -1010,7 +1057,7 @@ class DecodeWorker:
         occ = allocated / (self.num_blocks - 1)
         self.metrics.sample_cache(occ, actual, allocated * self._pb["fp"])
         tr = self.tracer
-        if tr.enabled or self.roofline_gauges:
+        if tr.enabled:
             extra = {}
             if self.prefix is not None:
                 # physical pages saved by sharing right now: each extra
@@ -1019,15 +1066,6 @@ class DecodeWorker:
                     rc - 1 for rc in self.alloc._rc.values() if rc > 1)
             tr.counter(self._trk_decode, "cache", occupancy=round(occ, 6),
                        frozen_pages=frozen, **extra)
-            m = modeled_decode_hbm_bytes(self)
-            if m is not None:
-                self.metrics.stats.gauge("hbm_bytes_per_token").set(
-                    m["hbm_bytes_per_token"])
-                self.metrics.stats.gauge("t_memory_s").set(m["t_memory_s"])
-                tr.counter(self._trk_decode, "roofline",
-                           hbm_bytes_per_token=round(
-                               m["hbm_bytes_per_token"], 3),
-                           t_memory_us=round(m["t_memory_s"] * 1e6, 6))
 
 
 @dataclasses.dataclass
@@ -1092,7 +1130,12 @@ class PrefillWorker:
         self.queue: deque[Request] = deque()
         self._inflight = None  # (req, blocks, logits device array, payload,
         #                         token offset the prefill started at)
-        self.counters = {"prefills": 0, "queue_peak": 0, "prefill_chunks": 0}
+        self.counters = {"prefills": 0, "queue_peak": 0, "prefill_chunks": 0,
+                         "prefill_ns": 0, "prefill_tokens": 0}
+        # prefill time and tokens are booked where the decode counters of
+        # the composition live: the borrowed pool's, else this worker's
+        self._phase_counters = (pool.counters if pool is not None
+                                else self.counters)
         self._prefill_fn = functools.partial(_prefill_step, cfg=cfg)
         self._chunk_fn = functools.partial(_prefill_chunk_step, cfg=cfg)
         if pool is None:
@@ -1166,90 +1209,91 @@ class PrefillWorker:
         """Launch one prompt's prefill (and, when migrating frozen, the
         page-freeze solve chained behind it); returns without waiting."""
         tr = self.tracer
-        t0 = tr.now()
-        self.metrics.prefill_start(req.id, now_fn())
         P = req.prompt_len
-        # async span across dispatch -> harvest: the device-side lifetime
-        # of this prompt's prefill (and any chained freeze solve)
-        tr.async_begin(self._trk, "prefill", req.id, rid=req.id,
-                       prompt_len=P)
-        ppad = -(-P // self.block_size) * self.block_size
-        nblk = ppad // self.block_size
-        off = 0
-        if self.pool is not None:
-            # borrowed pool: splice any published shared prefix, then
-            # allocate the request's remaining worst-case pages where they
-            # will be served; the handoff is a table splice
-            shared = self._match_prefix(req)
-            off = len(shared) * self.block_size
-            blocks = shared + self.pool.alloc.alloc(
-                self.pool.sched.blocks_for(req) - len(shared))
-            tree = self.pool.tree
-        else:
-            blocks = self.alloc.alloc(nblk)
-            tree = self.tree
-        toks = np.zeros((1, ppad - off), np.int32)
-        toks[0, :P - off] = req.prompt[off:]
-        table = np.asarray([blocks[:nblk]], np.int32)
-        tree1 = with_tables(tree, table, np.full((1,), off, np.int32))
-        if off:
-            # mid-sequence start past the shared pages: explicit positions
-            # rope/mask this exactly like the matching slice of a
-            # whole-prompt prefill (the chunked-prefill q_offset path)
-            if self.pool.attn_impl == "fused":
-                tree1 = with_prefill_fused(tree1)
-            pos = jnp.asarray(np.arange(off, ppad, dtype=np.int32)[None])
-            logits, new1 = self._chunk_fn(self.params, jnp.asarray(toks),
-                                          pos, tree1)
-        else:
-            logits, new1 = self._prefill_fn(self.params, jnp.asarray(toks),
-                                            tree1)
-        merged = merge_pools(tree, new1)
-        if self.pool is not None:
-            self.pool.tree = merged
-            payload = PagePayload(mode="splice",
-                                  blocks=[int(b) for b in blocks],
-                                  n_tokens=P, block_size=self.block_size,
-                                  n_full=P // self.block_size,
-                                  tail_rows=P % self.block_size,
-                                  shared_pages=off // self.block_size)
-        else:
-            self.tree = merged
-            payload = extract_pages(merged, blocks, P,
-                                    block_size=self.block_size,
-                                    mode=self.migrate, spec=self.kv_spec,
-                                    tracer=tr)
-        self._inflight = (req, blocks, logits, payload, off)
-        tr.complete(self._trk, "dispatch", t0, rid=req.id, prompt_len=P,
-                    pages=nblk, shared=off // self.block_size)
+        with phase(tr, self._trk, "dispatch", self._phase_counters,
+                   "prefill_ns", rid=req.id, prompt_len=P) as p:
+            self.metrics.prefill_start(req.id, now_fn())
+            # async span across dispatch -> harvest: the device-side lifetime
+            # of this prompt's prefill (and any chained freeze solve)
+            tr.async_begin(self._trk, "prefill", req.id, rid=req.id,
+                           prompt_len=P)
+            ppad = -(-P // self.block_size) * self.block_size
+            nblk = ppad // self.block_size
+            off = 0
+            if self.pool is not None:
+                # borrowed pool: splice any published shared prefix, then
+                # allocate the request's remaining worst-case pages where they
+                # will be served; the handoff is a table splice
+                shared = self._match_prefix(req)
+                off = len(shared) * self.block_size
+                blocks = shared + self.pool.alloc.alloc(
+                    self.pool.sched.blocks_for(req) - len(shared))
+                tree = self.pool.tree
+            else:
+                blocks = self.alloc.alloc(nblk)
+                tree = self.tree
+            toks = np.zeros((1, ppad - off), np.int32)
+            toks[0, :P - off] = req.prompt[off:]
+            table = np.asarray([blocks[:nblk]], np.int32)
+            tree1 = with_tables(tree, table, np.full((1,), off, np.int32))
+            if off:
+                # mid-sequence start past the shared pages: explicit positions
+                # rope/mask this exactly like the matching slice of a
+                # whole-prompt prefill (the chunked-prefill q_offset path)
+                if self.pool.attn_impl == "fused":
+                    tree1 = with_prefill_fused(tree1)
+                pos = jnp.asarray(np.arange(off, ppad, dtype=np.int32)[None])
+                logits, new1 = self._chunk_fn(self.params, jnp.asarray(toks),
+                                              pos, tree1)
+            else:
+                logits, new1 = self._prefill_fn(self.params, jnp.asarray(toks),
+                                                tree1)
+            merged = merge_pools(tree, new1)
+            if self.pool is not None:
+                self.pool.tree = merged
+                payload = PagePayload(mode="splice",
+                                      blocks=[int(b) for b in blocks],
+                                      n_tokens=P, block_size=self.block_size,
+                                      n_full=P // self.block_size,
+                                      tail_rows=P % self.block_size,
+                                      shared_pages=off // self.block_size)
+            else:
+                self.tree = merged
+                payload = extract_pages(merged, blocks, P,
+                                        block_size=self.block_size,
+                                        mode=self.migrate, spec=self.kv_spec,
+                                        tracer=tr)
+            self._inflight = (req, blocks, logits, payload, off)
+            self._phase_counters["prefill_tokens"] += P - off
+            p.args.update(pages=nblk, shared=off // self.block_size)
 
     def _harvest(self, now_fn) -> FinishedPrefill:
         """Materialize the finished prefill: sample the first token, stage
         the payload to host, release this worker's blocks."""
         tr = self.tracer
-        t0 = tr.now()
         req, blocks, logits, payload, off = self._inflight
         self._inflight = None
-        last = np.asarray(logits[0, req.prompt_len - 1 - off])
-        now = now_fn()                        # TTFT includes prefill time
-        rng = req.make_rng()
-        tok = sample_token(last, temperature=req.temperature,
-                           top_k=req.top_k, rng=rng)
-        self.metrics.first_token(req.id, now)
-        if payload.mode == "splice":
-            payload.to_host()  # lint: sync(splice mode stages no arrays)
-        else:
-            t_host = tr.now()
-            # lint: sync(handoff staging is the wire; gated on is_ready)
-            payload.to_host()
-            tr.complete("transfer", "to_host", t_host, rid=req.id,
-                        mode=payload.mode, bytes=payload.nbytes,
-                        fp_equiv_bytes=payload.fp_equiv_bytes,
-                        pages=payload.n_pages)
-        if self.pool is None:
-            self.alloc.free(blocks)           # pages left as a host payload
-        self.counters["prefills"] += 1
-        tr.complete(self._trk, "harvest", t0, rid=req.id)
+        with phase(tr, self._trk, "harvest", self._phase_counters,
+                   "prefill_ns", rid=req.id):
+            last = np.asarray(logits[0, req.prompt_len - 1 - off])
+            now = now_fn()                    # TTFT includes prefill time
+            rng = req.make_rng()
+            tok = sample_token(last, temperature=req.temperature,
+                               top_k=req.top_k, rng=rng)
+            self.metrics.first_token(req.id, now)
+            if payload.mode == "splice":
+                payload.to_host()  # lint: sync(splice mode stages no arrays)
+            else:
+                t_host = tr.now()
+                # lint: sync(handoff staging is the wire; gated on is_ready)
+                payload.to_host()
+                tr.complete("transfer", "to_host", t_host, rid=req.id,
+                            mode=payload.mode, bytes=payload.nbytes,
+                            fp_equiv_bytes=payload.fp_equiv_bytes,
+                            pages=payload.n_pages)
+            if self.pool is None:
+                self.alloc.free(blocks)       # pages left as a host payload
+            self.counters["prefills"] += 1
         tr.async_end(self._trk, "prefill", req.id, rid=req.id)
         return FinishedPrefill(
             req=req, first_token=tok, payload=payload, rng=rng,
@@ -1324,54 +1368,47 @@ class PrefillWorker:
         (bitwise on the gather path; see tests/test_properties.py).
         """
         tr = self.tracer
-        t0 = tr.now()
         req, P = state.req, state.req.prompt_len
         ppad = state.toks.shape[1]
         off = state.off
         C = min(self.prefill_chunk, ppad - off)
         pool = self.pool
-        toks = jnp.asarray(state.toks[:, off:off + C])
-        pos = jnp.asarray(np.arange(off, off + C, dtype=np.int32)[None])
-        table = np.zeros((1, state.nblk), np.int32)
-        table[0] = state.blocks[:state.nblk]
-        tree1 = with_tables(pool.tree, table, np.full((1,), off, np.int32))
-        if pool.attn_impl == "fused":
-            tree1 = with_prefill_fused(tree1)
-        logits, new1 = self._chunk_fn(self.params, toks, pos, tree1)
-        pool.tree = merge_pools(pool.tree, new1)
-        if off <= P - 1 < off + C:
-            state.last_row = logits[0, P - 1 - off]
-        state.off = off + C
-        self.counters["prefill_chunks"] += 1
-        if tr.enabled or pool.roofline_gauges:
-            m = modeled_prefill_hbm_bytes(
-                pool._pb, state.blocks, pool._frozen_pages,
-                block_size=self.block_size, off=off, chunk=C,
-                fused=pool.attn_impl == "fused")
-            self.metrics.stats.gauge("prefill_hbm_bytes_per_token").set(
-                m["hbm_bytes_per_token"])
-            tr.counter(self._trk, "roofline",
-                       prefill_hbm_bytes_per_token=round(
-                           m["hbm_bytes_per_token"], 3))
-        tr.complete(self._trk, "prefill_chunk", t0, rid=req.id, off=off,
-                    chunk=C)
+        with phase(tr, self._trk, "prefill_chunk", self._phase_counters,
+                   "prefill_ns", rid=req.id, off=off, chunk=C):
+            toks = jnp.asarray(state.toks[:, off:off + C])
+            pos = jnp.asarray(np.arange(off, off + C, dtype=np.int32)[None])
+            table = np.zeros((1, state.nblk), np.int32)
+            table[0] = state.blocks[:state.nblk]
+            tree1 = with_tables(pool.tree, table,
+                                np.full((1,), off, np.int32))
+            if pool.attn_impl == "fused":
+                tree1 = with_prefill_fused(tree1)
+            logits, new1 = self._chunk_fn(self.params, toks, pos, tree1)
+            pool.tree = merge_pools(pool.tree, new1)
+            if off <= P - 1 < off + C:
+                state.last_row = logits[0, P - 1 - off]
+            state.off = off + C
+            self.counters["prefill_chunks"] += 1
+            self._phase_counters["prefill_tokens"] += max(min(C, P - off), 0)
         if not state.done:
             return None
         # -------- harvest: mirrors _harvest's splice branch
-        last = np.asarray(state.last_row)   # first-token sampling sync
-        now = now_fn()                        # TTFT includes all chunks
-        rng = req.make_rng()
-        tok = sample_token(last, temperature=req.temperature,
-                           top_k=req.top_k, rng=rng)
-        self.metrics.first_token(req.id, now)
-        payload = PagePayload(mode="splice",
-                              blocks=[int(b) for b in state.blocks],
-                              n_tokens=P, block_size=self.block_size,
-                              n_full=P // self.block_size,
-                              tail_rows=P % self.block_size,
-                              shared_pages=state.shared)
-        payload.to_host()                   # splice mode stages no arrays
-        self.counters["prefills"] += 1
+        with phase(tr, self._trk, "harvest", self._phase_counters,
+                   "prefill_ns", rid=req.id):
+            last = np.asarray(state.last_row)   # first-token sampling sync
+            now = now_fn()                      # TTFT includes all chunks
+            rng = req.make_rng()
+            tok = sample_token(last, temperature=req.temperature,
+                               top_k=req.top_k, rng=rng)
+            self.metrics.first_token(req.id, now)
+            payload = PagePayload(mode="splice",
+                                  blocks=[int(b) for b in state.blocks],
+                                  n_tokens=P, block_size=self.block_size,
+                                  n_full=P // self.block_size,
+                                  tail_rows=P % self.block_size,
+                                  shared_pages=state.shared)
+            payload.to_host()               # splice mode stages no arrays
+            self.counters["prefills"] += 1
         tr.async_end(self._trk, "prefill", req.id, rid=req.id)
         return FinishedPrefill(
             req=req, first_token=tok, payload=payload, rng=rng,

@@ -10,17 +10,19 @@ page-freeze span opened during a run must reach exactly one terminal state
 worker's freeze counters.
 """
 import json
+import time
 
 import numpy as np
 import pytest
 
 import jax
+from jax.profiler import ProfileData
 
 from repro import models
 from repro.configs import get_reduced_config
-from repro.obs import (FakeClock, MetricsExporter, NULL_TRACER, Registry,
-                       Tracer, count_events, prometheus_text, select_events,
-                       tracks_of)
+from repro.obs import (FakeClock, MetricsExporter, NULL_TRACER, NullTracer,
+                       PREFIX, Registry, Tracer, count_events, phase,
+                       prometheus_text, select_events, tracks_of)
 from repro.obs.stats import LogHistogram
 from repro.serving import ContinuousBatchingEngine, DisaggEngine, derive_draft
 from repro.serving.metrics import MetricsCollector, percentile
@@ -97,6 +99,46 @@ def test_null_tracer_is_inert(tmp_path):
     path = tmp_path / "never.json"
     NULL_TRACER.write(str(path))
     assert not path.exists()
+
+
+class _CountingTracer:
+    """Records the counter's value at each span's end."""
+
+    enabled = False
+
+    def __init__(self, counters):
+        self.counters, self.seen = counters, []
+
+    def now(self):
+        return 0.0
+
+    def complete(self, track, name, t0, **args):
+        self.seen.append((track, name, self.counters["k"], args))
+
+
+def test_phase_counts_time_without_a_tracer():
+    """With the no-op tracer a phase still adds its host time to the
+    counter; a tracer sees each span end only after its time is counted,
+    with the args set inside the phase."""
+    c = {"k": 0}
+    with phase(NULL_TRACER, "decode/w0", "dispatch", c, "k", blocks=3):
+        time.sleep(0.002)
+    assert c["k"] >= 2_000_000
+    tr = _CountingTracer(c)
+    with phase(tr, "decode/w0", "commit", c, "k") as p:
+        time.sleep(0.001)
+        p.args["finished"] = 2
+    assert tr.seen == [("decode/w0", "commit", c["k"], {"finished": 2})]
+    assert c["k"] >= 3_000_000
+    # a phase left by an exception counts its time and records no span
+    with pytest.raises(KeyError):
+        with phase(tr, "decode/w0", "sync", c, "k"):
+            raise KeyError("x")
+    assert len(tr.seen) == 1
+    # no counter key: only the span
+    with phase(tr, "engine", "iteration"):
+        pass
+    assert tr.seen[-1][:2] == ("engine", "iteration")
 
 
 def test_log_histogram_percentiles():
@@ -360,7 +402,7 @@ def test_freeze_spans_terminal_by_drain(model):
     assert set(states.values()) <= {"installed", "dropped", "rolled_back"}
     assert count_events(tr.events, name="flush", ph="X") \
         == s["freeze_dispatches"]
-    assert count_events(tr.events, name="install", ph="i") \
+    assert count_events(tr.events, name="install", ph="X") \
         == s["freeze_installs"]
     # dispatched markers never exceed opened spans
     assert count_events(tr.events, name="page_freeze", ph="n") <= nb
@@ -405,7 +447,7 @@ def test_six_component_spec_disagg_trace(model, tmp_path):
 @pytest.mark.serving
 def test_engine_exporter_jsonl(model, tmp_path):
     """An exporter hung off the run loop lands ≥1 strict-JSON line with
-    the live totals, and roofline gauges appear in the registry."""
+    the live totals and the per-step cache gauges."""
     cfg, params, prompts = model
     path = str(tmp_path / "m.jsonl")
     exp = MetricsExporter(path, interval_s=0.0)       # emit every step
@@ -416,7 +458,118 @@ def test_engine_exporter_jsonl(model, tmp_path):
     rows = [json.loads(ln) for ln in open(path)]
     assert rows and rows[-1]["completed"] == N_REQ
     assert rows[-1]["gen_tokens"] == N_REQ * GEN
-    # host-side modeled roofline gauges published per step
-    assert "hbm_bytes_per_token" in eng.metrics.stats
-    assert eng.metrics.stats.gauge("hbm_bytes_per_token").n > 0
+    assert "cache_occupancy" in rows[-1]
     prometheus_text(eng.metrics.snapshot())
+
+
+def _host_phases(directory) -> list[tuple[str, int, int]]:
+    """(name, start_ns, end_ns) of the program's annotations on the
+    profiler's host plane."""
+    path = sorted(directory.rglob("*.xplane.pb"))[-1]
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(PREFIX):
+                    out.append((e.name, int(e.start_ns),
+                                int(e.start_ns + e.duration_ns)))
+    return out
+
+
+@pytest.mark.serving
+def test_phases_on_the_profiler_clock(model, tmp_path):
+    """Under a profiler session the engine's phases are annotations on
+    the profiler's host plane: every decode step holds its dispatch, sync
+    and commit, and each freeze install is one span."""
+    cfg, params, prompts = model
+    eng = ContinuousBatchingEngine(
+        params, cfg, kv_quant="kmeans_ls@16", freeze_async=True, **GEOM)
+    with jax.profiler.trace(str(tmp_path)):
+        s = eng.run(make_requests(prompts, GEN))
+    ev = _host_phases(tmp_path)
+    by = {}
+    for name, a, b in ev:
+        by.setdefault(name, []).append((a, b))
+    steps = by["repro:decode/w0:decode_step"]
+    assert len(steps) == s["decode_steps"] > 0
+    for part in ("dispatch", "sync", "commit"):
+        inner = by[f"repro:decode/w0:{part}"]
+        assert len(inner) == len(steps)
+        assert all(any(sa <= a and b <= sb for sa, sb in steps)
+                   for a, b in inner), part
+    assert len(by.get("repro:freeze/w0:install", [])) \
+        == s["freeze_installs"] > 0
+    assert len(by["repro:freeze/w0:flush"]) == s["freeze_dispatches"]
+    assert {"repro:engine:iteration", "repro:engine:intake",
+            "repro:prefill/w0:dispatch", "repro:prefill/w0:harvest",
+            "repro:decode/w0:attach"} <= set(by)
+    # the counters kept beside the phases
+    for k in ("decode_dispatch_ns", "decode_sync_ns", "decode_commit_ns",
+              "freeze_flush_ns", "freeze_install_ns", "prefill_ns"):
+        assert s[k] > 0, k
+    assert s["prefill_tokens"] == N_REQ * PROMPT_LEN
+
+
+class _ReadsTracer(NullTracer):
+    """At each decode dispatch, counts the step's page reads and rows the
+    way a reader outside the program would: a loop over the live slots'
+    tables against the worker's frozen set."""
+
+    def __init__(self, W):
+        self.W, self.worker = W, None
+        self.reads = {"kv_pages_read": 0, "kv_pages_read_frozen": 0,
+                      "kv_rows_used": 0, "kv_rows_reserved": 0}
+
+    def complete(self, track, name, t0, **args):
+        if name != "dispatch" or not track.startswith("decode/"):
+            return
+        w, r = self.worker, self.reads
+        for i in w.sched.active_slots():
+            n = int(w.lens[i])
+            pages = -(-(n + self.W) // w.block_size)
+            r["kv_pages_read"] += pages
+            r["kv_pages_read_frozen"] += sum(
+                int(b) in w._frozen_pages for b in w.table[i, :pages])
+            r["kv_rows_used"] += n
+            r["kv_rows_reserved"] += len(w.slots[i].blocks) * w.block_size
+
+
+@pytest.mark.serving
+@pytest.mark.parametrize("speculate", [0, 2])
+def test_kv_read_counters_match_a_loop(model, speculate):
+    """The decode dispatch's numpy counts of pages read, frozen pages
+    read, rows held and rows reserved equal a per-page loop's."""
+    cfg, params, prompts = model
+    tr = _ReadsTracer(speculate + 1)
+    kw = dict(speculate=speculate, draft=derive_draft(params, cfg)) \
+        if speculate else {}
+    eng = ContinuousBatchingEngine(
+        params, cfg, kv_quant="kmeans_ls@16", freeze_async=True, tracer=tr,
+        **GEOM, **kw)
+    tr.worker = eng.worker
+    s = eng.run(make_requests(prompts, GEN))
+    assert {k: s[k] for k in tr.reads} == tr.reads
+    assert 0 < s["kv_pages_read_frozen"] < s["kv_pages_read"]
+    assert 0 < s["kv_rows_used"] < s["kv_rows_reserved"]
+
+
+@pytest.mark.serving
+def test_trace_reconcile_counts_install_spans(model):
+    """``serve --trace-out``'s reconciliation holds one ``install`` span
+    per counted freeze install: a trace missing one does not reconcile."""
+    from repro.launch.serve import _trace_reconcile
+
+    cfg, params, prompts = model
+    tr = Tracer(clock=FakeClock())
+    eng = ContinuousBatchingEngine(
+        params, cfg, kv_quant="kmeans_ls@16", freeze_async=True, tracer=tr,
+        **GEOM)
+    s = eng.run(make_requests(prompts, GEN))
+    assert s["freeze_installs"] > 0
+    assert _trace_reconcile(tr, s, speculate=0)
+    i = next(j for j, e in enumerate(tr.events)
+             if e["name"] == "install" and e["ph"] == "X")
+    del tr.events[i]
+    assert not _trace_reconcile(tr, s, speculate=0)
