@@ -95,7 +95,8 @@ LANES = 128
 
 def codebook_lookup(idx: jax.Array, cb_at, L: int) -> jax.Array:
     """``cb[idx]`` for an (L,) codebook whose entries ``cb_at(l)`` are
-    scalars (SMEM reads in-kernel), as an L-way compare-and-select.
+    scalars (SMEM reads in-kernel) or arrays that broadcast against ``idx``
+    (one codebook per page), as an L-way compare-and-select.
 
     Mosaic lowers no vector gather from a 1-D table. Exactly one of the L
     selects matches each element, so the result equals ``jnp.take``

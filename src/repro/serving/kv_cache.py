@@ -66,6 +66,7 @@ from repro.core import QuantSpec
 from repro.core import registry as quant_registry
 from repro.kernels import (default_interpret, pack4, paged_decode_attention,
                            paged_prefill_attention, unpack4)
+from repro.kernels.paged_attention import codebook_lookup
 
 # ------------------------------------------------------------- allocator
 
@@ -631,11 +632,11 @@ def _install_leaf(leaf: PagedKVCache, jb, keep, codes, cb):
 
     def recon(codes1, cb1, cur):
         idx = _unpack4(codes1) if leaf.packed else codes1.astype(jnp.int32)
-        L = cb1.shape[-1]
-        cbb = jnp.broadcast_to(cb1[..., None, None, :],
-                               idx.shape[:-1] + (L,))    # (G?, P, bs, H, L)
-        deq = jnp.take_along_axis(cbb, idx, axis=-1).astype(leaf.k_fp.dtype)
-        return jnp.where(kpage, deq, cur)
+        # a TPU runs a per-element gather as a scalar loop over every
+        # element; an L-way select chain is a few vector ops instead
+        deq = codebook_lookup(idx, lambda l: cb1[..., l, None, None, None],
+                              cb1.shape[-1])
+        return jnp.where(kpage, deq.astype(leaf.k_fp.dtype), cur)
 
     kf = recon(codes[0], cb[0], leaf.k_fp[sel])
     vf = recon(codes[1], cb[1], leaf.v_fp[sel])

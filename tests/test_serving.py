@@ -246,6 +246,87 @@ def test_all_16_codes_dequantize_exactly():
     assert np.asarray(got.blk_q)[1]
 
 
+def _install_leaf_ref(leaf, jb, keep, codes, cb):
+    """The gather install, in numpy: every element looks its page's
+    codebook up with take_along_axis, then kept pages are scattered."""
+    from repro.serving.kv_cache import PagedKVCache
+
+    stacked = leaf.k_fp.ndim == 5
+    out = {f: np.array(getattr(leaf, f)) for f in PagedKVCache._POOL_LEAVES}
+    for t, tag in enumerate("kv"):
+        c, cbt = np.asarray(codes[t]), np.asarray(cb[t])
+        idx = (np.concatenate([c & 0xF, c >> 4], axis=-3) if leaf.packed
+               else c).astype(np.int64)
+        cbb = np.broadcast_to(cbt[..., None, None, :],
+                              idx.shape[:-1] + cbt.shape[-1:])
+        deq = np.take_along_axis(cbb, idx, axis=-1).astype(
+            out[f"{tag}_fp"].dtype)
+        for p, b in enumerate(np.asarray(jb)):
+            if not keep[p]:
+                continue
+            at = (slice(None), b) if stacked else (b,)
+            src = (slice(None), p) if stacked else (p,)
+            out[f"{tag}_fp"][at] = deq[src]
+            out[f"{tag}_codes"][at] = c[src]
+            out[f"{tag}_cb"][at] = cbt[src]
+            out["blk_q"][at] = True
+    return out
+
+
+@pytest.mark.parametrize("stacked", [False, True], ids=["flat", "stacked"])
+@pytest.mark.parametrize("L", [16, 32, 256],
+                         ids=["packed16", "unpacked32", "unpacked256"])
+def test_install_leaf_matches_gather_bitwise(L, stacked):
+    """_install_leaf writes every pool leaf exactly as the per-element
+    take_along_axis install does: packed and unpacked codebooks, flat and
+    group-stacked leaves, a dropped page, and a bucket padded with a
+    duplicate of its last page."""
+    from repro.serving.kv_cache import PagedKVCache, _install_leaf
+
+    cfg = _mini_cfg()
+    bs, nb, G = 4, 6, 3
+    leaf = init_paged_layer(cfg, num_blocks=nb, block_size=bs, batch=1,
+                            max_blocks=2, quantized=True, num_values=L,
+                            dtype=jnp.bfloat16)
+    if stacked:
+        leaf = jax.tree.map(
+            lambda a: jnp.broadcast_to(a, (G,) + a.shape).copy(), leaf)
+    rng = np.random.default_rng(L + stacked)
+    lead = leaf.k_fp.shape[:-4]                 # (G,) or ()
+
+    def rand(a):                                 # a non-trivial pool state
+        if a.dtype == jnp.uint8:
+            return jnp.asarray(rng.integers(0, 256, a.shape), jnp.uint8)
+        if a.dtype == jnp.bool_:
+            return jnp.asarray(rng.random(a.shape) < 0.5)
+        return jnp.asarray(rng.normal(size=a.shape), a.dtype)
+
+    leaf = dataclasses.replace(leaf, **{
+        f: rand(getattr(leaf, f)) for f in PagedKVCache._POOL_LEAVES})
+    jb = jnp.asarray([1, 2, 4, 4], jnp.int32)   # padded bucket of 4
+    keep = np.array([True, False, True, True])  # page 2 was dropped
+    P = jb.shape[0]
+    rows = leaf.k_codes.shape[-3]
+    full = rng.integers(0, L, (2,) + lead + (P, bs) + leaf.k_fp.shape[-2:])
+    page = full.shape[-3:]
+    full[..., 0, :, :, :] = np.arange(np.prod(page)).reshape(page) % L
+    codes = _pack4(full.astype(np.uint8)) if leaf.packed \
+        else full.astype(np.uint8)
+    assert codes.shape[-3] == rows and leaf.packed == (L <= 16)
+    cb = rng.normal(size=(2,) + lead + (P, L)).astype(np.float32)
+    codes[..., 3, :, :, :] = codes[..., 2, :, :, :]   # duplicate solves alike
+    cb[..., 3, :] = cb[..., 2, :]
+
+    got = _install_leaf(leaf, jb, jnp.asarray(keep), jnp.asarray(codes),
+                        jnp.asarray(cb))
+    want = _install_leaf_ref(leaf, jb, keep, codes, cb)
+    for f in PagedKVCache._POOL_LEAVES:
+        g = np.asarray(getattr(got, f))
+        assert g.dtype == want[f].dtype and g.shape == want[f].shape, f
+        np.testing.assert_array_equal(g.view(np.uint8),
+                                      want[f].view(np.uint8), err_msg=f)
+
+
 def test_null_page_write_masking():
     """Idle slots (table all-null) write into block 0; live pages stay
     untouched."""

@@ -91,6 +91,50 @@ def test_fista_quant_compiles(one_chip):
         ((2 * N_LAYERS * 4, 1, 1), jnp.float32))
 
 
+def _compile_install(one_chip, L, packed, P=4):
+    """Optimized text of ``_install_leaf`` at qwen3-0.6b widths (28 stacked
+    layers) for a P-page bucket of L-value codebooks."""
+    from repro.serving.kv_cache import PagedKVCache, _install_leaf
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows = BS // 2 if packed else BS
+    pool = (N_LAYERS, NB, BS, HKV, DH)
+    codes = (N_LAYERS, NB, rows, HKV, DH)
+    leaf = PagedKVCache(
+        k_fp=arg(pool, jnp.bfloat16), v_fp=arg(pool, jnp.bfloat16),
+        k_codes=arg(codes, jnp.uint8), v_codes=arg(codes, jnp.uint8),
+        k_cb=arg((N_LAYERS, NB, L), jnp.float32),
+        v_cb=arg((N_LAYERS, NB, L), jnp.float32),
+        blk_q=arg((N_LAYERS, NB), jnp.bool_),
+        block_table=arg((N_LAYERS, B, MB), jnp.int32),
+        seq_lens=arg((N_LAYERS, B), jnp.int32),
+        block_size=BS, quantized=True, packed=packed, fused=True)
+    return _install_leaf.lower(
+        leaf, arg((P,), jnp.int32), arg((P,), jnp.bool_),
+        arg((2, N_LAYERS, P, rows, HKV, DH), jnp.uint8),
+        arg((2, N_LAYERS, P, L), jnp.float32)).compile().as_text()
+
+
+def test_freeze_install_dequantizes_without_a_gather(one_chip):
+    """The freeze install at qwen3-0.6b widths (28 stacked layers, a
+    4-page bucket) looks its 4-bit codes up by compare-and-select: no op of
+    the optimized program comes from ``take_along_axis``, which the TPU
+    runs as a scalar loop over every element of the frozen pages."""
+    text = _compile_install(one_chip, L, packed=True)
+    assert "_install_leaf" in text
+    assert "take_along_axis" not in text
+
+
+def test_freeze_install_widest_codebook_compiles(one_chip):
+    """The widest unpacked codebook (256 values, one byte per code) takes
+    the same 256-way compare-and-select and compiles for the chip."""
+    text = _compile_install(one_chip, 256, packed=False)
+    assert "_install_leaf" in text
+    assert "take_along_axis" not in text
+
+
 # ------------------------------------------------- names the trace shows
 
 METRICS = Path(__file__).resolve().parents[1] / "bench" / "metrics"
