@@ -423,11 +423,13 @@ def modeled_hbm_bytes_per_token(
 
     ``seq_lens`` are pre-write lengths (the kernel sees valid = len + 1).
     The gather path materializes every table column for every row at full
-    width (frozen pages' reconstructions live in the fp pool, so every page
-    crosses HBM at fp_bytes/value); the fused path reads, per sequence,
-    only ``ceil((len+1)/bs)`` pages, each as *either* codes+codebooks
-    (frozen, ~4 bits/value) or fp (hot). K and V both counted; q/output
-    traffic is identical for both paths and excluded.
+    width (it gathers every page's fp rows, frozen ones included, before
+    selecting the frozen pages' dequantized codes, so every page crosses
+    HBM at fp_bytes/value; the codes it also reads are not counted); the
+    fused path reads, per sequence, only ``ceil((len+1)/bs)`` pages, each
+    as *either* codes+codebooks (frozen, ~4 bits/value) or fp (hot). K and
+    V both counted; q/output traffic is identical for both paths and
+    excluded.
     """
     table = np.asarray(block_table)
     lens = np.asarray(seq_lens)

@@ -24,6 +24,29 @@ def ref_quant_matmul_stacked(x, idx, codebook, out_dtype=None):
     return out.astype(out_dtype or x.dtype)
 
 
+def ref_paged_pages(fp, codes, cb, blk_q, block_table, *, quantized=False,
+                    packed=True):
+    """Every table page at full width, (B, mb*bs, Hkv, Dh): frozen pages
+    (``blk_q``) as ``cb[codes]`` by a per-element gather, cast to the pool
+    dtype, the rest as their fp rows."""
+    from .paged_attention import unpack4
+
+    t = block_table
+    B, mb = t.shape
+    nb, bs, Hkv, Dh = fp.shape
+    pages = fp[t]                                   # (B, mb, bs, H, D)
+    if quantized:
+        c = codes[t]
+        if packed:
+            c = unpack4(c)
+        deq = jnp.take_along_axis(
+            cb[t], c.reshape(B, mb, -1).astype(jnp.int32), axis=-1
+        ).reshape(c.shape)
+        frozen = blk_q.astype(bool)[t][:, :, None, None, None]
+        pages = jnp.where(frozen, deq.astype(pages.dtype), pages)
+    return pages.reshape(B, mb * bs, Hkv, Dh)
+
+
 def ref_paged_decode(q, k_fp, v_fp, k_codes, v_codes, k_cb, v_cb, blk_q,
                      block_table, kv_valid_len, *, softcap=None,
                      quantized=False, packed=True):
@@ -36,7 +59,7 @@ def ref_paged_decode(q, k_fp, v_fp, k_codes, v_codes, k_cb, v_cb, blk_q,
     speculative verify window whose query w sits at sequence position
     ``kv_valid_len - W + w`` (causal within the window).
     """
-    from .paged_attention import BIG_NEG, unpack4
+    from .paged_attention import BIG_NEG
 
     windowed = q.ndim == 4
     if not windowed:
@@ -44,24 +67,12 @@ def ref_paged_decode(q, k_fp, v_fp, k_codes, v_codes, k_cb, v_cb, blk_q,
     B, W, Hq, Dh = q.shape
     nb, bs, Hkv, _ = k_fp.shape
     G = Hq // Hkv
-    t = block_table
-    mb = t.shape[1]
-
-    def expand(fp, codes, cb):
-        pages = fp[t]                                   # (B, mb, bs, H, D)
-        if quantized:
-            c = codes[t]
-            if packed:
-                c = unpack4(c)
-            deq = jnp.take_along_axis(
-                cb[t], c.reshape(B, mb, -1).astype(jnp.int32), axis=-1
-            ).reshape(c.shape)
-            frozen = blk_q.astype(bool)[t][:, :, None, None, None]
-            pages = jnp.where(frozen, deq.astype(pages.dtype), pages)
-        return pages.reshape(B, mb * bs, Hkv, Dh)
-
-    k_all = expand(k_fp, k_codes, k_cb).astype(jnp.float32)
-    v_all = expand(v_fp, v_codes, v_cb).astype(jnp.float32)
+    mb = block_table.shape[1]
+    k_all, v_all = (ref_paged_pages(fp, codes, cb, blk_q, block_table,
+                                    quantized=quantized, packed=packed
+                                    ).astype(jnp.float32)
+                    for fp, codes, cb in ((k_fp, k_codes, k_cb),
+                                          (v_fp, v_codes, v_cb)))
     qr = q.astype(jnp.float32).reshape(B, W, Hkv, G, Dh)
     s = jnp.einsum("bwhgd,bshd->bwhgs", qr, k_all,
                    preferred_element_type=jnp.float32) / jnp.sqrt(Dh * 1.0)

@@ -12,8 +12,10 @@ cache exactly like ``transformer.init_lm_cache``):
                 unpacked when codebooks exceed 16 values.
   k_cb/v_cb     (nb, L) f32        per-block codebooks from the paper's
                 solvers (kmeans_ls / tv via repro.core.quantize).
-  blk_q         (nb,) bool         page i is frozen: codes are
-                authoritative, fp holds their reconstruction.
+  blk_q         (nb,) bool         page i is frozen: its codes and
+                codebook are its value. Its fp rows keep what they held
+                (the exact pre-freeze values, or nothing meaningful for a
+                page that landed as codes) and no read serves them.
   block_table   (B, mb) int32      per-sequence page ids (0 = null page).
   seq_lens      (B,) int32         per-sequence lengths (write positions).
 
@@ -27,11 +29,11 @@ page takes a ``QuantSpec`` (see ``resolve_kv_spec``) and is split into
 through the spec's registry device solver (kmeans_ls/kmeans via the exact
 DP sketch, iter_l1 via batched FISTA + per-row lambda bisection) in one
 async dispatch per layer — and ``install_freeze``, which scatters the
-finished codes/codebooks and flips ``blk_q``. Between the two, the pages
-keep serving from the exact fp pool, so decode steps carry no data
-dependency on the solve and truly overlap it; no host numpy runs in the
-steady state (count methods without a device entry keep the per-page host
-fallback).
+finished codes/codebooks and flips ``blk_q`` (the fp pools are not
+written). Between the two, the pages keep serving from the exact fp pool,
+so decode steps carry no data dependency on the solve and truly overlap
+it; no host numpy runs in the steady state (count methods without a device
+entry keep the per-page host fallback).
 
 Reads have two paths:
 
@@ -42,11 +44,10 @@ Reads have two paths:
       Frozen pages cross the wire at ~4 bits/value.
 
   gather (CPU / prefill / fallback)   ``update`` expands every table page
-      to full width from the fp pool and returns dense K/V for the
-      caller's sdpa. Installing a freeze *materializes* ``cb[codes]`` into
-      the frozen pages' fp rows, so this path serves exactly the quantized
-      values with a decode graph identical to the unquantized one — it is
-      the reference the fused kernel is validated against, paying fp
+      to full width and returns dense K/V for the caller's sdpa: fp rows
+      for live pages, ``cb[codes]`` for frozen ones (dequantized in the
+      graph, and only when the table holds a frozen page). It is the
+      reference the fused kernel is validated against, paying fp
       bandwidth where the kernel pays ~4 bits/value.
 
 ``PagedKVCache`` implements the adapter protocol of ``repro.models.cache``
@@ -61,6 +62,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core import QuantSpec
 from repro.core import registry as quant_registry
@@ -275,6 +277,8 @@ class PagedKVCache:
                "blk_q", "block_table", "seq_lens")
     _POOL_LEAVES = ("k_fp", "v_fp", "k_codes", "v_codes", "k_cb", "v_cb",
                     "blk_q")
+    # what a freeze install writes: a frozen page's whole value
+    _CODE_LEAVES = ("k_codes", "v_codes", "k_cb", "v_cb", "blk_q")
 
     def tree_flatten(self):
         return (tuple(getattr(self, f) for f in self._LEAVES),
@@ -368,21 +372,31 @@ class PagedKVCache:
             interpret=default_interpret())
         return new, out.astype(q.dtype)
 
-    def _gather(self, fp, codes=None, cb=None):
+    def _gather(self, fp, codes, cb):
         """Pages for this batch: (B, mb*bs, Hkv, Dh).
 
-        No read-time dequantization: installing a freeze materializes the
-        reconstruction ``cb[codes]`` into the frozen pages' fp rows (see
-        ``_install_leaf``), so this path reads plain fp yet returns
-        quantized values for frozen pages — the decode graph is identical
-        to the unquantized one. ``codes``/``cb`` are accepted for call-site
-        symmetry; the packed form is read only by the fused kernel, which
-        is where the ~4-bit HBM crossing actually pays."""
-        del codes, cb
+        A frozen page reads as ``cb[codes]`` (the fused kernel's L-way
+        compare-and-select) cast to the pool dtype, a live page as its fp
+        rows; a frozen page's fp rows are never served. The lookup runs
+        only when the table holds a frozen page, so a read of fresh pages
+        (a whole-prompt prefill) is the plain fp gather."""
         t = self.block_table                                # (B, mb)
         B, mb = t.shape
         pages = fp[t]                                       # (B,mb,bs,H,D)
         nb, bs, H, D = fp.shape
+        if self.quantized:
+            frozen = self.blk_q[t]                          # (B, mb)
+            c, cbt = codes[t], cb[t]                        # cbt (B, mb, L)
+
+            def dequantize(pages):
+                idx = _unpack4(c) if self.packed else c.astype(jnp.int32)
+                deq = codebook_lookup(
+                    idx, lambda l: cbt[..., l, None, None, None],
+                    cbt.shape[-1])
+                return jnp.where(frozen[..., None, None, None],
+                                 deq.astype(fp.dtype), pages)
+
+            pages = lax.cond(jnp.any(frozen), dequantize, lambda p: p, pages)
         return pages.reshape(B, mb * bs, H, D)
 
 
@@ -612,13 +626,13 @@ def _solve_leaf_pages(leaf: PagedKVCache, jb, *, spec: QuantSpec):
 
 @jax.jit
 def _install_leaf(leaf: PagedKVCache, jb, keep, codes, cb):
-    """Scatter one solve's outputs into a leaf, masked by ``keep`` (P,):
-    dropped pages rewrite their current values and stay thawed. Installing
-    also *materializes the reconstruction into the fp pool*, so the gather
-    read path serves quantized values at plain-fp cost; the packed codes
-    stay the source of truth for the fused kernel's ~4-bit HBM reads. One
-    jit dispatch — eager scatter chains on still-computing operands can
-    block the host."""
+    """Scatter one solve's outputs into a leaf's codes, codebooks and
+    ``blk_q``, masked by ``keep`` (P,): dropped pages rewrite their current
+    values and stay thawed. Returns only those leaves (``_CODE_LEAVES``),
+    so the fp pools are neither written nor outputs of the program: a
+    frozen page's value is its codes and codebook, and every read
+    dequantizes them. One jit dispatch — eager scatter chains on
+    still-computing operands can block the host."""
     stacked = leaf.k_fp.ndim == 5
     sel = (slice(None), jb) if stacked else (jb,)
     # align keep to the (G?, P, ...) result layout of _solve_leaf_pages
@@ -629,26 +643,11 @@ def _install_leaf(leaf: PagedKVCache, jb, keep, codes, cb):
     vc = jnp.where(kpage, codes[1], leaf.v_codes[sel])
     kcb = jnp.where(kcb_m, cb[0], leaf.k_cb[sel])
     vcb = jnp.where(kcb_m, cb[1], leaf.v_cb[sel])
-
-    def recon(codes1, cb1, cur):
-        idx = _unpack4(codes1) if leaf.packed else codes1.astype(jnp.int32)
-        # a TPU runs a per-element gather as a scalar loop over every
-        # element; an L-way select chain is a few vector ops instead
-        deq = codebook_lookup(idx, lambda l: cb1[..., l, None, None, None],
-                              cb1.shape[-1])
-        return jnp.where(kpage, deq.astype(leaf.k_fp.dtype), cur)
-
-    kf = recon(codes[0], cb[0], leaf.k_fp[sel])
-    vf = recon(codes[1], cb[1], leaf.v_fp[sel])
-    return dataclasses.replace(
-        leaf,
-        k_fp=leaf.k_fp.at[sel].set(kf),
-        v_fp=leaf.v_fp.at[sel].set(vf),
-        k_codes=leaf.k_codes.at[sel].set(kc),
-        v_codes=leaf.v_codes.at[sel].set(vc),
-        k_cb=leaf.k_cb.at[sel].set(kcb),
-        v_cb=leaf.v_cb.at[sel].set(vcb),
-        blk_q=leaf.blk_q.at[..., jb].max(keep))
+    return dict(k_codes=leaf.k_codes.at[sel].set(kc),
+                v_codes=leaf.v_codes.at[sel].set(vc),
+                k_cb=leaf.k_cb.at[sel].set(kcb),
+                v_cb=leaf.v_cb.at[sel].set(vcb),
+                blk_q=leaf.blk_q.at[..., jb].max(keep))
 
 
 class PendingFreeze:
@@ -718,7 +717,8 @@ def install_freeze(tree, pending: PendingFreeze):
     """Scatter a completed (or still-computing) freeze into the cache and
     flip ``blk_q``; from the next step the kept pages serve from codes.
     Stacked leaves broadcast ``keep``/``codes`` over the group axis inside
-    ``_install_leaf`` via the (2, G, P, ...) result layout."""
+    ``_install_leaf`` via the (2, G, P, ...) result layout; each leaf keeps
+    its fp pools as they were."""
     if not pending.keep.any():
         return tree
     jb = jnp.asarray(pending.bids)
@@ -727,7 +727,8 @@ def install_freeze(tree, pending: PendingFreeze):
 
     def per(leaf: PagedKVCache):
         codes, cb = next(it)
-        return _install_leaf(leaf, jb, keep, codes, cb)
+        return dataclasses.replace(leaf, **_install_leaf(leaf, jb, keep,
+                                                         codes, cb))
 
     return map_layers(per, tree)
 
@@ -750,38 +751,31 @@ def _freeze_blocks_host(tree, bids, spec: QuantSpec, *, stats=None):
         vf = np.asarray(jnp.take(leaf.v_fp, jb, axis=axis))
         kc, vc = leaf.k_codes, leaf.v_codes
         kcb, vcb = leaf.k_cb, leaf.v_cb
-        kfp, vfp = leaf.k_fp, leaf.v_fp
         for g in groups:
             sel = () if g is None else (g,)
             for pool, tag in ((kf, "k"), (vf, "v")):
-                new_codes, new_cbs, new_recon = [], [], []
+                new_codes, new_cbs = [], []
                 for bi in range(len(bids)):
                     codes, cb = quantize_page(pool[sel + (bi,)], spec)
                     if stats is not None:
                         stats["host_page_solves"] = (
                             stats.get("host_page_solves", 0) + 1)
-                    new_recon.append(cb[codes])
                     if leaf.packed:
                         codes = _pack4(codes)
                     new_codes.append(codes)
                     new_cbs.append(cb)
                 nc = jnp.asarray(np.stack(new_codes))
                 ncb = jnp.asarray(np.stack(new_cbs))
-                # materialize the reconstruction into the fp rows so the
-                # gather read path serves quantized values at plain-fp cost
-                nr = jnp.asarray(np.stack(new_recon), leaf.k_fp.dtype)
                 if tag == "k":
                     kc = kc.at[sel + (bids,)].set(nc)
                     kcb = kcb.at[sel + (bids,)].set(ncb)
-                    kfp = kfp.at[sel + (bids,)].set(nr)
                 else:
                     vc = vc.at[sel + (bids,)].set(nc)
                     vcb = vcb.at[sel + (bids,)].set(ncb)
-                    vfp = vfp.at[sel + (bids,)].set(nr)
         blk_q = leaf.blk_q.at[..., bids].set(True)
-        return dataclasses.replace(leaf, k_fp=kfp, v_fp=vfp, k_codes=kc,
-                                   v_codes=vc, k_cb=kcb, v_cb=vcb,
-                                   blk_q=blk_q)
+        # like the device install, the fp rows are left as they are
+        return dataclasses.replace(leaf, k_codes=kc, v_codes=vc, k_cb=kcb,
+                                   v_cb=vcb, blk_q=blk_q)
 
     return map_layers(per, tree)
 

@@ -23,11 +23,11 @@ Three migration modes:
              codebook (~7x fewer bytes than fp at 16 values) and are
              installed on the destination through the same
              ``install_freeze`` used by in-place freezing — which scatters
-             codes/codebooks, flips ``blk_q``, and materializes the
-             reconstruction into the fp rows, so the landed pages are
-             directly servable by both the fused kernel (codes) and the
-             gather path (fp reconstruction). Only the trailing partial
-             page still crosses fp.
+             codes/codebooks and flips ``blk_q``, so the landed pages are
+             directly servable by both the fused kernel and the gather
+             path, which both read frozen pages as codes. Their fp rows
+             are left as the destination had them and never served. Only
+             the trailing partial page still crosses fp.
 
   "resident" Overload demotion (``extract_resident_pages``): capture a
              LIVE sequence's pages exactly as currently served — pages
@@ -165,8 +165,9 @@ class FinishedPrefill:
 
 
 def _take_pages(leaf: PagedKVCache, bids) -> jnp.ndarray:
-    """k and v pages ``bids`` stacked on a leading axis:
-    (2, G?, P, bs, Hkv, Dh)."""
+    """k and v fp rows of pages ``bids`` stacked on a leading axis:
+    (2, G?, P, bs, Hkv, Dh). Callers take only pages that are not
+    installed frozen (a frozen page's fp rows are not its value)."""
     axis = 1 if leaf.k_fp.ndim == 5 else 0
     jb = jnp.asarray(np.asarray(bids, np.int32))
     return jnp.stack([jnp.take(leaf.k_fp, jb, axis=axis),
@@ -338,8 +339,8 @@ def splice_payload(tree, payload: PagePayload, new_blocks, *,
     it = iter(out)
     tree = map_layers(lambda _leaf: next(it), tree)
     if payload.frozen is not None:
-        # same install path as in-place freezing: scatters codes/codebooks,
-        # flips blk_q, and materializes the reconstruction into the fp rows
+        # same install path as in-place freezing: scatters codes/codebooks
+        # and flips blk_q; the pages' fp rows are never read again
         pending = PendingFreeze(
             fro_full, [(jnp.asarray(c), jnp.asarray(cb))
                        for c, cb in payload.frozen])

@@ -220,7 +220,10 @@ class DecodeWorker:
                          # the live sequences hold against the rows of
                          # the pages reserved for them
                          "kv_pages_read": 0, "kv_pages_read_frozen": 0,
-                         "kv_rows_used": 0, "kv_rows_reserved": 0}
+                         "kv_rows_used": 0, "kv_rows_reserved": 0,
+                         # per prefill on the gather path, summed: the
+                         # installed-frozen pages its read dequantizes
+                         "kv_gather_frozen_pages": 0}
         # radix/hash prefix index over installed-frozen (or, unquantized,
         # sequence-passed) full prompt pages; sequences attach published
         # pages at rc > 1 instead of re-prefilling them
@@ -490,6 +493,14 @@ class DecodeWorker:
         c["kv_rows_used"] += int(lens.sum())
         c["kv_rows_reserved"] += bs * sum(len(self.slots[i].blocks)
                                           for i in active)
+
+    def count_prefill_read(self, table: np.ndarray, fused: bool) -> None:
+        """Count the installed-frozen pages of a prefill's ``table`` that
+        its gather-path read dequantizes (``PagedKVCache._gather``); a
+        fused read takes them as codes and counts none."""
+        if not fused:
+            self.counters["kv_gather_frozen_pages"] += int(
+                np.count_nonzero(self._frozen_mask[table]))
 
     # ------------------------------------------------------- speculative
 
@@ -783,7 +794,7 @@ class DecodeWorker:
         # drop one reference per page; teardown side effects (span drops,
         # bid/frozen forgetting, thawing, index invalidation) scope to the
         # pages actually RELEASED — a shared prefix page another live table
-        # still references keeps serving its frozen reconstruction
+        # still references keeps serving its codes
         released = set(self.alloc.free(s.blocks))
         if self.prefix is not None:
             self.prefix.invalidate(released)
@@ -977,11 +988,10 @@ class DecodeWorker:
         self._mark_frozen(blocks[m + j] for j in entry.frozen_idx)
         # frozen_upto is the maximal frozen PREFIX; installs land in queue
         # order so the frozen set is a prefix in practice. If it ever
-        # weren't, _queue_freeze would re-solve an already-frozen page —
-        # value-exact (kmeans_ls on a 16-distinct-value reconstruction
-        # reproduces it), so at most a redundant solve, never divergence.
-        # A quantized shared prefix is installed-frozen by construction, so
-        # it extends the watermark from page 0.
+        # weren't, _queue_freeze still skips the pages marked frozen just
+        # above: a frozen page is never solved again (its fp rows are not
+        # its value). A quantized shared prefix is installed-frozen by
+        # construction, so it extends the watermark from page 0.
         fset = {m + j for j in entry.frozen_idx}
         if m and self.kv_spec is not None:
             fset |= set(range(m))
@@ -1029,6 +1039,7 @@ class DecodeWorker:
         tree1 = with_tables(self.tree, table, np.zeros((1,), np.int32))
         if self.attn_impl == "fused":
             tree1 = with_prefill_fused(tree1)
+        self.count_prefill_read(table, self.attn_impl == "fused")
         _, new = _prefill_chunk_step(self.params, jnp.asarray(toks), pos,
                                      tree1, cfg=self.cfg)
         self.tree = merge_pools(self.tree, new)
@@ -1236,6 +1247,11 @@ class PrefillWorker:
             toks[0, :P - off] = req.prompt[off:]
             table = np.asarray([blocks[:nblk]], np.int32)
             tree1 = with_tables(tree, table, np.full((1,), off, np.int32))
+            if self.pool is not None:
+                # a whole-prompt prefill reads through the gather path; so
+                # does a shared-prefix one unless the pool's reads are fused
+                self.pool.count_prefill_read(
+                    table, bool(off) and self.pool.attn_impl == "fused")
             if off:
                 # mid-sequence start past the shared pages: explicit positions
                 # rope/mask this exactly like the matching slice of a
@@ -1383,6 +1399,7 @@ class PrefillWorker:
                                 np.full((1,), off, np.int32))
             if pool.attn_impl == "fused":
                 tree1 = with_prefill_fused(tree1)
+            pool.count_prefill_read(table, pool.attn_impl == "fused")
             logits, new1 = self._chunk_fn(self.params, toks, pos, tree1)
             pool.tree = merge_pools(pool.tree, new1)
             if off <= P - 1 < off + C:

@@ -211,10 +211,41 @@ def _mini_cfg():
 # ------------------------------------------------------------- transfer
 
 
+def _read_pages(leaf, pages):
+    """The gather read (``PagedKVCache._gather``) of one table row
+    ``pages`` from a flat or group-stacked leaf: k and v, each
+    (G?, len(pages) * bs, Hkv, Dh)."""
+    table = jnp.asarray([pages], jnp.int32)
+
+    def one(layer):
+        layer = dataclasses.replace(layer, block_table=table)
+        return [np.asarray(layer._gather(fp, c, cb))[0] for fp, c, cb in (
+            (layer.k_fp, layer.k_codes, layer.k_cb),
+            (layer.v_fp, layer.v_codes, layer.v_cb))]
+
+    if leaf.k_fp.ndim == 4:
+        return one(leaf)
+    groups = [one(jax.tree.map(lambda a, g=g: a[g], leaf))
+              for g in range(leaf.k_fp.shape[0])]
+    return [np.stack([kv[t] for kv in groups]) for t in range(2)]
+
+
+def _recon(codes, cb, pages, ax):
+    """cb[codes] of packed 4-bit ``pages`` by numpy take_along_axis:
+    (G?, len(pages), bs, Hkv, Dh) float32."""
+    c = np.take(codes, pages, axis=ax)
+    idx = np.concatenate([c & 0xF, c >> 4], axis=-3).astype(np.int64)
+    cbp = np.take(cb, pages, axis=ax)
+    return np.take_along_axis(
+        cbp, idx.reshape(idx.shape[:ax + 1] + (-1,)), axis=-1
+    ).reshape(idx.shape)
+
+
 def test_transfer_roundtrip_fp_and_frozen():
     """extract -> to_host -> splice lands the same page content in a fresh
-    pool: fp pages bit-exact, frozen pages as the codebook reconstruction
-    with blk_q set and codes identical to an in-place freeze."""
+    pool: fp pages bit-exact, frozen pages as codes and codebooks identical
+    to an in-place freeze, with blk_q set, fp rows untouched, and a gather
+    read of the codebook reconstruction."""
     from repro.serving import freeze_blocks
 
     cfg = _mini_cfg()
@@ -255,16 +286,29 @@ def test_transfer_roundtrip_fp_and_frozen():
                                               take(s_k, blocks[:2]))
                 assert not np.asarray(dl.blk_q)[..., new_blocks[:2]].any()
             else:
-                # frozen pages land as cb[codes], identical to freezing the
-                # same pages in place on the source pool
+                # frozen pages land as the codes and codebooks of freezing
+                # the same pages in place on the source pool; their fp
+                # rows stay as the destination had them (zeros)
                 ref = freeze_blocks(sl, blocks[:2], spec)
-                np.testing.assert_allclose(take(d_k, new_blocks[:2]),
-                                           take(np.asarray(ref.k_fp),
-                                                blocks[:2]), rtol=1e-6)
-                np.testing.assert_array_equal(
-                    take(np.asarray(dl.k_codes), new_blocks[:2]),
-                    take(np.asarray(ref.k_codes), blocks[:2]))
+                assert not take(d_k, new_blocks[:2]).any()
+                assert not take(np.asarray(dl.v_fp), new_blocks[:2]).any()
+                for f in ("k_codes", "v_codes", "k_cb", "v_cb"):
+                    np.testing.assert_array_equal(
+                        take(np.asarray(getattr(dl, f)), new_blocks[:2]),
+                        take(np.asarray(getattr(ref, f)), blocks[:2]),
+                        err_msg=f)
                 assert np.asarray(dl.blk_q)[..., new_blocks[:2]].all()
+                # and the gather read serves them as cb[codes], bit for bit
+                # what the in-place freeze serves on the source
+                read = _read_pages(dl, new_blocks[:2])
+                for got, want in zip(read, _read_pages(ref, blocks[:2])):
+                    np.testing.assert_array_equal(got, want)
+                for got, tag in zip(read, "kv"):
+                    recon = _recon(np.asarray(getattr(dl, f"{tag}_codes")),
+                                   np.asarray(getattr(dl, f"{tag}_cb")),
+                                   new_blocks[:2], ax)
+                    np.testing.assert_array_equal(
+                        got, recon.astype(got.dtype).reshape(got.shape))
             # the partial tail page crosses fp in both modes (valid rows)
             np.testing.assert_array_equal(
                 take(d_k, [new_blocks[2]])[..., 0, :4, :, :],

@@ -217,10 +217,29 @@ def test_pack4_roundtrip(bs):
         np.asarray(unpack4(pack4(jnp.asarray(codes)))), codes)
 
 
+def _read_pages(leaf, pages):
+    """The gather read (``PagedKVCache._gather``) of one table row
+    ``pages`` from a flat or group-stacked leaf: k and v, each
+    (G?, len(pages) * bs, Hkv, Dh)."""
+    table = jnp.asarray([pages], jnp.int32)
+
+    def one(layer):
+        layer = dataclasses.replace(layer, block_table=table)
+        return [np.asarray(layer._gather(fp, c, cb))[0] for fp, c, cb in (
+            (layer.k_fp, layer.k_codes, layer.k_cb),
+            (layer.v_fp, layer.v_codes, layer.v_cb))]
+
+    if leaf.k_fp.ndim == 4:
+        return one(leaf)
+    groups = [one(jax.tree.map(lambda a, g=g: a[g], leaf))
+              for g in range(leaf.k_fp.shape[0])]
+    return [np.stack([kv[t] for kv in groups]) for t in range(2)]
+
+
 def test_all_16_codes_dequantize_exactly():
-    """Installing a freeze whose codes sweep all 16 values materializes
-    exactly cb[codes] into the fp rows (the packed install/gather path) and
-    serves it through _gather."""
+    """Installing a freeze whose codes sweep all 16 values leaves the fp
+    rows alone, stores the codes and codebook, and the gather read serves
+    exactly cb[codes] (the packed install/gather path)."""
     from repro.serving.kv_cache import PendingFreeze, install_freeze
 
     cfg = _mini_cfg()
@@ -229,6 +248,10 @@ def test_all_16_codes_dequantize_exactly():
     leaf = init_paged_layer(cfg, num_blocks=3, block_size=bs, batch=1,
                             max_blocks=1, quantized=True, num_values=16,
                             dtype=jnp.float32)
+    rng = np.random.default_rng(1)
+    leaf = dataclasses.replace(
+        leaf, k_fp=jnp.asarray(rng.normal(size=leaf.k_fp.shape), jnp.float32),
+        v_fp=jnp.asarray(rng.normal(size=leaf.v_fp.shape), jnp.float32))
     codes = (np.arange(bs * Hkv * Dh) % 16).astype(np.uint8).reshape(
         bs, Hkv, Dh)
     cb = np.linspace(-2.0, 2.0, 16).astype(np.float32)
@@ -240,48 +263,57 @@ def test_all_16_codes_dequantize_exactly():
     got = install_freeze(dataclasses.replace(
         leaf, block_table=jnp.asarray([[1]], np.int32),
         seq_lens=jnp.asarray([bs], np.int32)), pending)
-    np.testing.assert_allclose(np.asarray(got.k_fp)[1], cb[codes])
-    k_all = got._gather(got.k_fp, got.k_codes, got.k_cb)
-    np.testing.assert_allclose(np.asarray(k_all)[0], cb[codes])
+    for f in ("k_fp", "v_fp"):                # the fp rows are not written
+        np.testing.assert_array_equal(np.asarray(getattr(got, f)),
+                                      np.asarray(getattr(leaf, f)))
+    np.testing.assert_array_equal(np.asarray(got.k_codes)[1], packed[0])
+    np.testing.assert_array_equal(np.asarray(got.k_cb)[1], cb)
     assert np.asarray(got.blk_q)[1]
+    k_all = got._gather(got.k_fp, got.k_codes, got.k_cb)
+    np.testing.assert_array_equal(np.asarray(k_all)[0], cb[codes])
 
 
 def _install_leaf_ref(leaf, jb, keep, codes, cb):
-    """The gather install, in numpy: every element looks its page's
-    codebook up with take_along_axis, then kept pages are scattered."""
+    """The install, in numpy: kept pages' codes, codebooks and flags are
+    scattered and their fp rows left alone. Also returns each bucket
+    page's reconstruction, every element looked up in its page's codebook
+    with take_along_axis and cast to the pool dtype: (2, G?, P, bs, H, D)."""
     from repro.serving.kv_cache import PagedKVCache
 
     stacked = leaf.k_fp.ndim == 5
     out = {f: np.array(getattr(leaf, f)) for f in PagedKVCache._POOL_LEAVES}
+    deqs = []
     for t, tag in enumerate("kv"):
         c, cbt = np.asarray(codes[t]), np.asarray(cb[t])
         idx = (np.concatenate([c & 0xF, c >> 4], axis=-3) if leaf.packed
                else c).astype(np.int64)
         cbb = np.broadcast_to(cbt[..., None, None, :],
                               idx.shape[:-1] + cbt.shape[-1:])
-        deq = np.take_along_axis(cbb, idx, axis=-1).astype(
-            out[f"{tag}_fp"].dtype)
+        deqs.append(np.take_along_axis(cbb, idx, axis=-1).astype(
+            out[f"{tag}_fp"].dtype))
         for p, b in enumerate(np.asarray(jb)):
             if not keep[p]:
                 continue
             at = (slice(None), b) if stacked else (b,)
             src = (slice(None), p) if stacked else (p,)
-            out[f"{tag}_fp"][at] = deq[src]
             out[f"{tag}_codes"][at] = c[src]
             out[f"{tag}_cb"][at] = cbt[src]
             out["blk_q"][at] = True
-    return out
+    return out, np.stack(deqs)
 
 
 @pytest.mark.parametrize("stacked", [False, True], ids=["flat", "stacked"])
 @pytest.mark.parametrize("L", [16, 32, 256],
                          ids=["packed16", "unpacked32", "unpacked256"])
 def test_install_leaf_matches_gather_bitwise(L, stacked):
-    """_install_leaf writes every pool leaf exactly as the per-element
-    take_along_axis install does: packed and unpacked codebooks, flat and
-    group-stacked leaves, a dropped page, and a bucket padded with a
-    duplicate of its last page."""
-    from repro.serving.kv_cache import PagedKVCache, _install_leaf
+    """_install_leaf writes the codes, codebooks and flags exactly as the
+    numpy install does and returns no fp pool (``install_freeze`` keeps
+    the leaf's fp rows byte for byte), and the gather read of every
+    installed page equals the per-element take_along_axis reconstruction:
+    packed and unpacked codebooks, flat and group-stacked leaves, a
+    dropped page, and a bucket padded with a duplicate of its last page."""
+    from repro.serving.kv_cache import (PagedKVCache, PendingFreeze,
+                                        _install_leaf, install_freeze)
 
     cfg = _mini_cfg()
     bs, nb, G = 4, 6, 3
@@ -317,14 +349,62 @@ def test_install_leaf_matches_gather_bitwise(L, stacked):
     codes[..., 3, :, :, :] = codes[..., 2, :, :, :]   # duplicate solves alike
     cb[..., 3, :] = cb[..., 2, :]
 
-    got = _install_leaf(leaf, jb, jnp.asarray(keep), jnp.asarray(codes),
+    out = _install_leaf(leaf, jb, jnp.asarray(keep), jnp.asarray(codes),
                         jnp.asarray(cb))
-    want = _install_leaf_ref(leaf, jb, keep, codes, cb)
+    assert sorted(out) == sorted(PagedKVCache._CODE_LEAVES)
+    pending = PendingFreeze(np.asarray(jb),
+                            [(jnp.asarray(codes), jnp.asarray(cb))])
+    pending.keep = keep
+    got = install_freeze(leaf, pending)
+    want, deq = _install_leaf_ref(leaf, jb, keep, codes, cb)
     for f in PagedKVCache._POOL_LEAVES:
         g = np.asarray(getattr(got, f))
         assert g.dtype == want[f].dtype and g.shape == want[f].shape, f
         np.testing.assert_array_equal(g.view(np.uint8),
                                       want[f].view(np.uint8), err_msg=f)
+        if f in out:
+            np.testing.assert_array_equal(np.asarray(out[f]).view(np.uint8),
+                                          want[f].view(np.uint8), err_msg=f)
+    # the gather read of the installed pages, 1 and 4 (bucket slots 0, 2)
+    for t, read in enumerate(_read_pages(got, [1, 4])):
+        sel = (slice(None), [0, 2]) if stacked else ([0, 2],)
+        ref = deq[t][sel].reshape(read.shape)
+        np.testing.assert_array_equal(read.view(np.uint8),
+                                      ref.view(np.uint8))
+
+
+def test_gather_reads_fp_unless_frozen():
+    """A table holding no frozen page reads its fp rows exactly; one
+    holding frozen pages reads what the oracle's expansion reads
+    (``kernels.ref.ref_paged_pages``, a per-element gather), live pages
+    as fp and frozen ones as cb[codes], bit for bit."""
+    from repro.kernels.ref import ref_paged_pages
+
+    cfg = _mini_cfg()
+    bs = 4
+    leaf = init_paged_layer(cfg, num_blocks=7, block_size=bs, batch=2,
+                            max_blocks=3, quantized=True, num_values=16,
+                            dtype=jnp.bfloat16)
+    rng = np.random.default_rng(11)
+    leaf = dataclasses.replace(
+        leaf, k_fp=jnp.asarray(rng.normal(size=leaf.k_fp.shape), jnp.bfloat16),
+        v_fp=jnp.asarray(rng.normal(size=leaf.v_fp.shape), jnp.bfloat16))
+    leaf = freeze_blocks(leaf, [1, 4])
+    assert np.asarray(leaf.blk_q)[[1, 4]].all()
+    for table, any_frozen in (([[2, 3, 5], [6, 5, 0]], False),
+                              ([[1, 2, 4], [3, 4, 0]], True)):
+        t = jnp.asarray(table, jnp.int32)
+        cur = dataclasses.replace(leaf, block_table=t)
+        for fp, c, cb in ((cur.k_fp, cur.k_codes, cur.k_cb),
+                          (cur.v_fp, cur.v_codes, cur.v_cb)):
+            got = np.asarray(cur._gather(fp, c, cb))
+            want = np.asarray(ref_paged_pages(fp, c, cb, cur.blk_q, t,
+                                              quantized=True, packed=True))
+            np.testing.assert_array_equal(got.view(np.uint16),
+                                          want.view(np.uint16))
+            plain = np.asarray(fp)[np.asarray(table)].reshape(got.shape)
+            assert np.array_equal(got.view(np.uint16),
+                                  plain.view(np.uint16)) != any_frozen
 
 
 def test_null_page_write_masking():
@@ -366,27 +446,30 @@ def test_freeze_thaw_dequantizes_within_tolerance():
         block_table=jnp.asarray([[1, 2]], np.int32),
         seq_lens=jnp.asarray([2 * bs], np.int32))
     frozen = freeze_blocks(leaf, [1, 2], method="kmeans_ls", num_values=16)
+    # the freeze writes codes and codebooks only: the fp rows keep the
+    # exact pre-freeze values
+    np.testing.assert_array_equal(np.asarray(frozen.k_fp), kd)
+    np.testing.assert_array_equal(np.asarray(frozen.v_fp), kd * 0.5)
     k_all = frozen._gather(frozen.k_fp, frozen.k_codes, frozen.k_cb)
     ref = np.concatenate([kd[1], kd[2]], axis=0)
     err = np.abs(np.asarray(k_all)[0] - ref)
     rms = np.sqrt((err ** 2).mean()) / np.sqrt((ref ** 2).mean())
     assert rms < 0.25, rms               # 16 shared values per page
-    # the gather path serves exactly the codebook reconstruction (install
-    # materialized cb[codes] into the fp rows)
-    recon = np.asarray(frozen.k_cb)[[1, 2]][
-        np.arange(2)[:, None],
+    # the gather path serves exactly the codebook reconstruction (it
+    # dequantizes the frozen pages' codes as it reads them)
+    recon = np.take_along_axis(
+        np.asarray(frozen.k_cb)[[1, 2]],
         np.asarray(_unpack4(frozen.k_codes[np.asarray([1, 2])])
-                   ).reshape(2, -1)].reshape(2, bs, *kd.shape[2:])
-    np.testing.assert_allclose(np.asarray(k_all)[0],
-                               recon.reshape(2 * bs, *kd.shape[2:]),
-                               rtol=1e-6)
-    # thaw: flag clears; the fp rows keep the reconstruction until the
-    # reallocated page is overwritten by its next sequence (the original
-    # values are gone once a page is frozen)
+                   ).reshape(2, -1), axis=-1).reshape(2, bs, *kd.shape[2:])
+    np.testing.assert_array_equal(np.asarray(k_all)[0],
+                                  recon.reshape(2 * bs, *kd.shape[2:]))
+    # thaw: the flag clears and the pages read their fp rows again, which
+    # still hold the exact pre-freeze values until the reallocated page is
+    # overwritten by its next sequence
     thawed = thaw_blocks(frozen, [1, 2])
     assert not np.asarray(thawed.blk_q)[[1, 2]].any()
     k_fp = thawed._gather(thawed.k_fp, thawed.k_codes, thawed.k_cb)
-    np.testing.assert_allclose(np.asarray(k_fp), np.asarray(k_all))
+    np.testing.assert_array_equal(np.asarray(k_fp)[0], ref)
 
 
 def test_quantize_page_tv_method():
@@ -556,6 +639,34 @@ def test_engine_fused_matches_gather(qwen_reduced):
     for i in range(len(prompts)):
         np.testing.assert_allclose(f_eng.request_logits[i],
                                    g_eng.request_logits[i], atol=1e-3, rtol=0)
+
+
+@pytest.mark.parametrize("prefix_cache", [False, True],
+                         ids=["whole_prompt", "prefix_hit"])
+def test_gather_prefill_counts_frozen_pages(qwen_reduced, prefix_cache):
+    """``kv_gather_frozen_pages`` reads 0 when every prefill is a
+    whole prompt over fresh pages, and counts the shared frozen pages a
+    prefix-hit prefill on the gather path dequantizes."""
+    cfg, params = qwen_reduced
+    rng = np.random.default_rng(12)
+    common = rng.integers(0, cfg.vocab, 16).tolist()    # two full pages
+    # two slots: the short request's slot frees while the long one still
+    # holds the (by then frozen and published) shared pages
+    reqs = [Request(id=i, prompt=tuple(common + rng.integers(
+                0, cfg.vocab, 5).tolist()), max_new_tokens=gen)
+            for i, gen in enumerate((10, 2, 3))]
+    eng = ContinuousBatchingEngine(
+        params, cfg, max_slots=2, block_size=8, max_seq_len=40,
+        kv_quant="kmeans_ls@16", attn_impl="gather", freeze_async=False,
+        freeze_page_budget=8, prefix_cache=prefix_cache)
+    s = eng.run(reqs)
+    assert sorted(eng.outputs) == [0, 1, 2]
+    if prefix_cache:
+        assert s["prefix_hits"] >= 1 and s["prefix_shared_pages"] >= 2
+        assert s["kv_gather_frozen_pages"] == s["prefix_shared_pages"]
+    else:
+        assert s["prefix_hits"] == 0
+        assert s["kv_gather_frozen_pages"] == 0
 
 
 def test_device_freeze_async_no_host_solves(qwen_reduced):

@@ -92,8 +92,8 @@ def test_fista_quant_compiles(one_chip):
 
 
 def _compile_install(one_chip, L, packed, P=4):
-    """Optimized text of ``_install_leaf`` at qwen3-0.6b widths (28 stacked
-    layers) for a P-page bucket of L-value codebooks."""
+    """``_install_leaf`` compiled at qwen3-0.6b widths (28 stacked layers)
+    for a P-page bucket of L-value codebooks."""
     from repro.serving.kv_cache import PagedKVCache, _install_leaf
 
     def arg(shape, dtype):
@@ -114,24 +114,65 @@ def _compile_install(one_chip, L, packed, P=4):
     return _install_leaf.lower(
         leaf, arg((P,), jnp.int32), arg((P,), jnp.bool_),
         arg((2, N_LAYERS, P, rows, HKV, DH), jnp.uint8),
-        arg((2, N_LAYERS, P, L), jnp.float32)).compile().as_text()
+        arg((2, N_LAYERS, P, L), jnp.float32)).compile()
 
 
 def test_freeze_install_dequantizes_without_a_gather(one_chip):
     """The freeze install at qwen3-0.6b widths (28 stacked layers, a
-    4-page bucket) looks its 4-bit codes up by compare-and-select: no op of
-    the optimized program comes from ``take_along_axis``, which the TPU
-    runs as a scalar loop over every element of the frozen pages."""
-    text = _compile_install(one_chip, L, packed=True)
+    4-page bucket) runs no per-element gather: no op of the optimized
+    program comes from ``take_along_axis``, which the TPU runs as a scalar
+    loop over every element of the frozen pages."""
+    text = _compile_install(one_chip, L, packed=True).as_text()
     assert "_install_leaf" in text
     assert "take_along_axis" not in text
 
 
 def test_freeze_install_widest_codebook_compiles(one_chip):
-    """The widest unpacked codebook (256 values, one byte per code) takes
-    the same 256-way compare-and-select and compiles for the chip."""
-    text = _compile_install(one_chip, 256, packed=False)
+    """The install of the widest unpacked codebook (256 values, one byte
+    per code) compiles for the chip with no per-element gather either."""
+    text = _compile_install(one_chip, 256, packed=False).as_text()
     assert "_install_leaf" in text
+    assert "take_along_axis" not in text
+
+
+def test_freeze_install_leaves_fp_pools_alone(one_chip):
+    """The freeze install at qwen3-0.6b widths writes codes, codebooks and
+    flags only: no op or output of the optimized program is a bf16 array
+    of an fp pool's size (a pool copy, in any shape), and its outputs
+    together are smaller than one fp pool."""
+    compiled = _compile_install(one_chip, L, packed=True)
+    text = compiled.as_text()
+    pool = N_LAYERS * NB * BS * HKV * DH
+    sizes = {int(np.prod([int(d) for d in dims.split(",") if d]))
+             for dims in re.findall(r"bf16\[([\d,]*)\]", text)}
+    assert pool not in sizes
+    out = compiled.memory_analysis().output_size_in_bytes
+    assert 0 < out < pool * jnp.dtype(jnp.bfloat16).itemsize, out
+
+
+@pytest.mark.parametrize("L,packed", [(16, True), (256, False)],
+                         ids=["packed16", "unpacked256"])
+def test_gather_read_dequantizes_without_a_gather(one_chip, L, packed):
+    """The gather read of a longctx prompt's 240-page table at qwen3-0.6b
+    widths dequantizes frozen pages by compare-and-select, behind a
+    conditional on whether the table holds one: no op comes from
+    ``take_along_axis``."""
+    from repro.serving.kv_cache import PagedKVCache
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows = BS // 2 if packed else BS
+    nb, mb = 2113, 240
+    leaf = PagedKVCache(
+        k_fp=arg((nb, BS, HKV, DH), jnp.bfloat16), v_fp=None,
+        k_codes=arg((nb, rows, HKV, DH), jnp.uint8), v_codes=None,
+        k_cb=arg((nb, L), jnp.float32), v_cb=None,
+        blk_q=arg((nb,), jnp.bool_), block_table=arg((1, mb), jnp.int32),
+        seq_lens=None, block_size=BS, quantized=True, packed=packed)
+    text = jax.jit(lambda c: c._gather(c.k_fp, c.k_codes, c.k_cb)).lower(
+        leaf).compile().as_text()
+    assert "conditional(" in text
     assert "take_along_axis" not in text
 
 
