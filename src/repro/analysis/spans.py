@@ -30,9 +30,10 @@ from typing import Iterable, Mapping
 from .lint import Finding, LintPass, Module, register
 
 #: declared lifecycles: span name -> exact set of terminal states its
-#: async_end sites must cover (workers.py's freeze/offload machines:
-#: page_freeze queued→dispatched→installed|dropped|rolled_back|offloaded,
-#: page_offload →restored)
+#: async_end sites must cover (page_freeze, held by
+#: kv_cache.FreezeQueue: queued→dispatched→installed|dropped|rolled_back|
+#: offloaded; page_offload, opened by DecodeWorker.preempt and ended by
+#: DecodeWorker.restore: →restored)
 MACHINES: dict[str, frozenset[str]] = {
     "page_freeze": frozenset(
         {"installed", "dropped", "rolled_back", "offloaded"}),

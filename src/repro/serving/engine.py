@@ -209,10 +209,6 @@ class ContinuousBatchingEngine:
     def _pb(self):
         return self.worker._pb
 
-    @property
-    def _pending_freezes(self):
-        return self.worker._pending_freezes
-
     # ------------------------------------------------------------ intake
 
     def submit(self, req: Request, now: float) -> bool:

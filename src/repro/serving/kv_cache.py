@@ -23,17 +23,28 @@ Block 0 is reserved as the null page: idle batch slots point every table
 entry at it, so their (masked) decode writes land in the trash instead of a
 live page.
 
-Writes always go to the fp pool inside the jitted step. Freezing a full
-page takes a ``QuantSpec`` (see ``resolve_kv_spec``) and is split into
-``dispatch_freeze`` — every (page, group, k/v) row of the event batched
-through the spec's registry device solver (kmeans_ls/kmeans via the exact
-DP sketch, iter_l1 via batched FISTA + per-row lambda bisection) in one
-async dispatch per layer — and ``install_freeze``, which scatters the
-finished codes/codebooks and flips ``blk_q`` (the fp pools are not
-written). Between the two, the pages keep serving from the exact fp pool,
-so decode steps carry no data dependency on the solve and truly overlap
-it; no host numpy runs in the steady state (count methods without a device
-entry keep the per-page host fallback).
+Writes always go to the fp pool inside the jitted step. A full page
+freezes under a ``QuantSpec`` (see ``resolve_kv_spec``) in two device
+steps: ``dispatch_freeze`` batches every (page, group, k/v) row of the
+event through the spec's registry device solver (kmeans_ls/kmeans via the
+exact DP sketch, iter_l1 via batched FISTA + per-row lambda bisection) in
+one async dispatch per layer, and ``install_freeze`` scatters the codes and
+codebooks and flips ``blk_q`` (the fp pools are not written). Between the
+two the pages serve exact fp, so decode steps overlap the solve; count
+methods without a device entry solve per page on the host.
+
+``FreezeQueue`` holds the host half for one pool. A page in it moves
+
+  queued -> dispatched -> installed | dropped | rolled_back | offloaded
+
+and its ``page_freeze`` span ends in one of the last four. The decode
+worker keeps each slot's watermark and calls ``bid`` as the slot's pages
+fill, ``flush`` at the start of every iteration (sync mode installs
+there), ``poll`` before each decode step, ``release`` when a slot finishes
+or is preempted (``dropped``/``offloaded``) or rolls back a speculative
+suffix (``rolled_back``), ``mark_installed`` for pages that arrive frozen
+(frozen migration, restore) and ``install_now`` to freeze a rebuilt
+shared prefix.
 
 Reads have two paths:
 
@@ -69,6 +80,7 @@ from repro.core import registry as quant_registry
 from repro.kernels import (default_interpret, pack4, paged_decode_attention,
                            paged_prefill_attention, unpack4)
 from repro.kernels.paged_attention import codebook_lookup
+from repro.obs.trace import phase
 
 # ------------------------------------------------------------- allocator
 
@@ -597,7 +609,8 @@ def freeze_blocks(tree, block_ids, spec=None, *, method=None,
     spec = resolve_kv_spec(spec, method=method, num_values=num_values)
     bids = np.asarray(sorted(block_ids), np.int32)
     if spec.device_capable:
-        return _freeze_blocks_device(tree, bids, spec)
+        # jax's dataflow still runs the solve async behind later dispatches
+        return install_freeze(tree, dispatch_freeze(tree, bids, spec))
     return _freeze_blocks_host(tree, bids, spec, stats=stats)
 
 
@@ -651,17 +664,11 @@ def _install_leaf(leaf: PagedKVCache, jb, keep, codes, cb):
 
 
 class PendingFreeze:
-    """Handle for an in-flight device freeze.
-
-    Holds the solver outputs (one (codes, cb) pair per layer leaf, still
-    computing on device) plus the page ids they target. Until ``install``
-    scatters them into the cache, those pages keep serving from the exact
-    fp pool — so decode steps issued between dispatch and install have NO
-    data dependency on the solve and genuinely overlap it. ``drop`` forgets
-    pages whose sequence finished (freed pages must not be installed later
-    over a reallocated page); it only flips a host-side mask, so it is free
-    to call while the solve is still in flight.
-    """
+    """Handle for an in-flight device freeze: the solver outputs (one
+    (codes, cb) pair per layer leaf, still computing on device) and the
+    page ids they target. ``drop`` forgets freed pages (they must not be
+    installed over a reallocated page); it only flips a host-side mask, so
+    it is free to call while the solve is still in flight."""
 
     def __init__(self, bids: np.ndarray, results: list):
         self.bids = np.asarray(bids, np.int32)
@@ -733,12 +740,6 @@ def install_freeze(tree, pending: PendingFreeze):
     return map_layers(per, tree)
 
 
-def _freeze_blocks_device(tree, bids, spec: QuantSpec):
-    # synchronous-semantics convenience: dispatch and install in one call
-    # (jax's dataflow still runs the solve async behind later dispatches)
-    return install_freeze(tree, dispatch_freeze(tree, bids, spec))
-
-
 def _freeze_blocks_host(tree, bids, spec: QuantSpec, *, stats=None):
     def per(leaf: PagedKVCache):
         assert leaf.quantized
@@ -793,6 +794,183 @@ def thaw_blocks(tree, block_ids):
                                    blk_q=leaf.blk_q.at[..., bids].set(False))
 
     return map_layers(per, tree)
+
+
+# ----------------------------------------------- page-freeze lifecycle
+
+
+class FreezeQueue:
+    """The page-freeze lifecycle of one pool (module docstring): the bid
+    queue, the in-flight solves, the installed set and its mask over block
+    ids, the open ``page_freeze`` spans and the deferred-page watermark.
+    ``counters`` is the worker's dict, where the freeze counters are
+    registered; ``on_install`` runs whenever pages become installed."""
+
+    def __init__(self, spec, *, budget: int, is_async: bool, num_blocks: int,
+                 tracer, track: str, counters: dict, on_install):
+        assert budget >= 1, "freeze budget must cover >= 1 page"
+        self.spec, self.budget, self.is_async = spec, budget, is_async
+        self.tracer, self.track = tracer, track
+        self.counters, self.on_install = counters, on_install
+        self.bids: list[int] = []          # queued for the next flush
+        # (decode step dispatched at, solve), in dispatch order
+        self.inflight: list[tuple[int, PendingFreeze]] = []
+        self.installed: set[int] = set()   # pages serving codes
+        self.mask = np.zeros(num_blocks, bool)
+        # page id -> open page_freeze span id; page_offload spans draw
+        # their ids from the same sequence (one per track)
+        self._spans: dict[int, int] = {}
+        self._span_seq = 0
+        self._deferred_seen = 0    # queue suffix already counted deferred
+
+    @property
+    def idle(self) -> bool:
+        """Nothing queued and nothing in flight."""
+        return not (self.bids or self.inflight)
+
+    def next_span_id(self) -> int:
+        self._span_seq += 1
+        return self._span_seq
+
+    def bid(self, pages, slot: int) -> None:
+        """Queue ``slot``'s ``pages`` for the next flush and open their
+        spans. Bids dedupe on block id: an installed page, or one already
+        bid (a shared page its owner bid), is skipped."""
+        tr = self.tracer
+        for b in pages:
+            b = int(b)
+            if b in self.installed or b in self.bids:
+                continue
+            self.bids.append(b)
+            if tr.enabled:
+                self._spans[b] = self.next_span_id()
+                tr.async_begin(self.track, "page_freeze", self._spans[b],
+                               page=b, slot=slot)
+
+    def mark_installed(self, pages) -> None:
+        """Record ``pages`` as serving codes and end their open spans
+        ``installed`` (pages that arrive frozen have none)."""
+        pages = sorted({int(b) for b in pages})
+        self.installed.update(pages)
+        self.mask[pages] = True
+        if self.tracer.enabled:
+            for b in pages:
+                sid = self._spans.pop(b, None)
+                if sid is not None:
+                    self.tracer.async_end(self.track, "page_freeze", sid,
+                                          state="installed", page=b)
+
+    def install_now(self, tree, pages):
+        """Solve and install ``pages`` synchronously."""
+        tree = freeze_blocks(tree, pages, self.spec, stats=self.counters)
+        self.mark_installed(pages)
+        return tree
+
+    def flush(self, tree):
+        """One batched solve for the queue's first ``budget`` pages: async
+        mode dispatches it, sync mode also installs it. The budget is the
+        backpressure valve — a prefill burst's pages spread over later
+        iterations, serving exact fp meanwhile — and
+        ``freeze_deferred_pages`` counts each page it defers once."""
+        if not self.bids:
+            return tree
+        tr, c = self.tracer, self.counters
+        take = min(len(self.bids), self.budget)
+        with phase(tr, self.track, "flush", c, "freeze_flush_ns",
+                   pages=take, mode="async" if self.is_async else "sync"):
+            bids, self.bids = self.bids[:take], self.bids[take:]
+            if tr.enabled:
+                for b in bids:
+                    sid = self._spans.get(b)
+                    if sid is not None:
+                        tr.async_instant(self.track, "page_freeze", sid,
+                                         state="dispatched")
+            # the flush took the oldest pages, hence any already counted,
+            # off the front: shrink the counted watermark by ``take``, then
+            # count what remains beyond it as newly deferred
+            self._deferred_seen = max(self._deferred_seen - take, 0)
+            c["freeze_deferred_pages"] += max(
+                len(self.bids) - self._deferred_seen, 0)
+            self._deferred_seen = len(self.bids)
+            if self.spec.device_capable:
+                # pad to a power-of-two page count (repeating one page is a
+                # no-op at install) so the jitted solver compiles a handful
+                # of shapes instead of one per distinct flush size; the host
+                # fallback solves per page, where a duplicate is pure waste
+                bucket = 1 << (len(bids) - 1).bit_length()
+                bids = bids + [bids[-1]] * (bucket - len(bids))
+            if self.is_async:
+                self.inflight.append((c["decode_steps"],
+                                      dispatch_freeze(tree, bids, self.spec)))
+                c["freeze_pending_max"] = max(c["freeze_pending_max"],
+                                              len(self.inflight))
+            else:
+                with phase(tr, self.track, "install",
+                           pages=len(set(bids)), wait_steps=0):
+                    tree = self.install_now(tree, bids)
+                self.on_install()
+                c["freeze_installs"] += 1
+            c["freeze_dispatches"] += 1
+        return tree
+
+    def poll(self, tree, drain: bool = False):
+        """Install the solves that landed; count the ones still overlapping
+        this decode step. ``drain=True`` blocks on the rest (end of run)."""
+        still, c = [], self.counters
+        for step0, pending in self.inflight:
+            if drain and not pending.is_ready():
+                # lint: sync(drain-only: end-of-run flush blocks by design)
+                jax.block_until_ready(pending.markers())
+            if pending.is_ready():
+                wait = c["decode_steps"] - step0
+                with phase(self.tracer, self.track, "install", c,
+                           "freeze_install_ns", wait_steps=wait) as p:
+                    tree = install_freeze(tree, pending)
+                    kept = pending.kept_pages()
+                    c["freeze_installs"] += 1
+                    c["freeze_overlap_steps"] += wait
+                    p.args["pages"] = len(kept)
+                    self.mark_installed(kept)
+            else:
+                c["freeze_inflight_steps"] += 1
+                still.append((step0, pending))
+        landed = len(still) < len(self.inflight)
+        self.inflight = still
+        if landed:
+            self.on_install()
+        return tree
+
+    def release(self, pages: set, state: str) -> None:
+        """Forget ``pages``: end their open spans in ``state``, take them
+        off the queue and out of in-flight solves (a landing solve leaves
+        them thawed). Freed pages (``dropped``, ``offloaded``) leave the
+        installed set; ``rolled_back`` pages lie past a slot's accepted
+        length, where nothing is installed, and their marks are left."""
+        assert state in ("dropped", "rolled_back", "offloaded"), state
+        tr = self.tracer
+        if tr.enabled:
+            # a literal state= per branch keeps the lifecycle statically
+            # checkable (repro.analysis span pass)
+            for b in sorted(pages):
+                sid = self._spans.pop(b, None)
+                if sid is None:
+                    continue
+                if state == "offloaded":
+                    tr.async_end(self.track, "page_freeze", sid,
+                                 state="offloaded", page=b)
+                elif state == "rolled_back":
+                    tr.async_end(self.track, "page_freeze", sid,
+                                 state="rolled_back", page=b)
+                else:
+                    tr.async_end(self.track, "page_freeze", sid,
+                                 state="dropped", page=b)
+        self.bids = [b for b in self.bids if b not in pages]
+        self._deferred_seen = min(self._deferred_seen, len(self.bids))
+        if state != "rolled_back":
+            self.installed -= pages
+            self.mask[list(pages)] = False
+        for _, pending in self.inflight:
+            pending.drop(pages)
 
 
 # ----------------------------------------------- footprint accounting

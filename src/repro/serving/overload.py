@@ -369,7 +369,8 @@ class OverloadManager:
 
         def rank(st):
             s = worker.slots[st.slot]
-            frozen = sum(1 for b in s.blocks if b in worker._frozen_pages)
+            frozen = sum(1 for b in s.blocks
+                         if b in worker.freezes.installed)
             frac = frozen / max(len(s.blocks), 1)
             return (worker.last_attended[st.slot], -frac, st.slot)
 
@@ -390,7 +391,7 @@ class OverloadManager:
         sh = worker.shared_prefix_pages(st.slot)
         full = int(worker.lens[st.slot]) // worker.block_size
         frozen = sum(1 for b in s.blocks[sh:full]
-                     if b in worker._frozen_pages)
+                     if b in worker.freezes.installed)
         n_pages = -(-int(worker.lens[st.slot]) // worker.block_size) - sh
         pb = worker._pb
         est = frozen * pb["frozen"] + (n_pages - frozen) * pb["fp"]

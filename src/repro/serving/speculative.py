@@ -35,7 +35,7 @@ target-side page sharing is invisible here. On the target, a shared page
 is installed-frozen before it is ever published, and rollback only touches
 pages past the accepted watermark — which is always past the shared prompt
 prefix — so speculative rollback can never un-freeze or mutate a page
-another table references; ``_queue_freeze``'s bid dedupe covers the rest.
+another table references; ``FreezeQueue.bid``'s dedupe covers the rest.
 """
 from __future__ import annotations
 

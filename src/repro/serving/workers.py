@@ -2,8 +2,8 @@
 prefill and decode roles.
 
 ``DecodeWorker`` owns a paged KV pool and the decode hot loop — iteration
-batching over its slots, async page freezing (batched sparse-LSQ device
-solves, rate-limited per decode step), eviction/recycling — behind a narrow
+batching over its slots, page freezing (driving the pool's
+``kv_cache.FreezeQueue``), eviction/recycling — behind a narrow
 ``step()`` / ``attach()`` interface. Sequences enter it only as finished
 prefills (``transfer.FinishedPrefill``): pages are spliced into its pool
 and decoding continues from the already-sampled first token.
@@ -41,9 +41,8 @@ import numpy as np
 from repro import models
 from repro.obs.trace import NULL_TRACER, phase
 
-from .kv_cache import (BlockAllocator, PrefixIndex, dispatch_freeze,
-                       freeze_blocks, init_paged_cache, install_freeze,
-                       merge_pools, page_bytes, thaw_blocks,
+from .kv_cache import (BlockAllocator, FreezeQueue, PrefixIndex,
+                       init_paged_cache, merge_pools, page_bytes, thaw_blocks,
                        with_prefill_fused, with_tables)
 from .scheduler import ContinuousBatchingScheduler, Request, SeqState
 from .speculative import DraftWorker, window_step
@@ -106,8 +105,9 @@ class _Slot:
 
 
 class DecodeWorker:
-    """The decode role: paged pool + iteration-batched decode loop + async
-    freeze machinery, fed through ``attach(seq_state, finished_prefill)``.
+    """The decode role: paged pool + iteration-batched decode loop, fed
+    through ``attach(seq_state, finished_prefill)``; its pages freeze
+    through ``self.freezes`` (a ``kv_cache.FreezeQueue``).
     """
 
     def __init__(self, params, cfg, *, worker_id: int = 0, max_slots: int = 8,
@@ -134,10 +134,6 @@ class DecodeWorker:
         self.max_seq_len = self.max_blocks * block_size
         self.num_blocks = (num_blocks if num_blocks is not None
                            else max_slots * self.max_blocks + 1)
-        self.freeze_async = (freeze_async and kv_spec is not None
-                             and kv_spec.device_capable)
-        assert freeze_page_budget >= 1, "freeze budget must cover >= 1 page"
-        self.freeze_page_budget = freeze_page_budget
         self.eos_id = eos_id
         self.record_logits = record_logits
         assert speculate >= 0
@@ -228,19 +224,13 @@ class DecodeWorker:
         # sequence-passed) full prompt pages; sequences attach published
         # pages at rc > 1 instead of re-prefilling them
         self.prefix = PrefixIndex(block_size) if prefix_cache else None
-        self._pending_freezes: list[tuple[int, object]] = []
-        self._freeze_bids: list[int] = []   # queued for the next flush
-        self._deferred_seen = 0    # queue suffix already counted deferred
-        self._frozen_pages: set[int] = set()   # installed (codes serving)
-        # the same set as a mask over block ids, for per-step read counts
-        self._frozen_mask = np.zeros(self.num_blocks, bool)
-        # freeze-lifecycle async spans: page id -> open span id. A span
-        # opens when a bid is queued and MUST end in exactly one terminal
-        # state — installed / dropped (seq finished first) / rolled_back
-        # (speculative suffix rejected) — which the obs property test
-        # checks against the dispatch/install counters.
-        self._page_spans: dict[int, int] = {}
-        self._span_seq = 0
+        self.freezes = FreezeQueue(
+            kv_spec, budget=freeze_page_budget,
+            is_async=(freeze_async and kv_spec is not None
+                      and kv_spec.device_capable),
+            num_blocks=self.num_blocks, tracer=self.tracer,
+            track=self._trk_freeze, counters=self.counters,
+            on_install=self._publish_prefixes)
         # overload machinery: per-slot LRU signal (decode step the slot
         # last attended) and, for recompute-path preemptions, the tokens
         # already emitted under the request's first life — merged back
@@ -289,8 +279,17 @@ class DecodeWorker:
 
     @property
     def has_work(self) -> bool:
-        return bool(self.sched.active or self._pending_freezes
-                    or self._freeze_bids)
+        return bool(self.sched.active) or not self.freezes.idle
+
+    @property
+    def freeze_async(self) -> bool:
+        return self.freezes.is_async
+
+    @property
+    def _frozen_pages(self) -> set[int]:
+        # read-only view of ``freezes.installed`` under the name the
+        # benchmark's harness reads (bench/benchkit/harness.py)
+        return self.freezes.installed
 
     # ------------------------------------------------------------ import
 
@@ -337,7 +336,7 @@ class DecodeWorker:
                 # pages landed as codes+codebooks: already frozen, never queue
                 # them for a second solve
                 s.frozen_upto = payload.n_full
-                self._mark_frozen(blocks[:payload.n_full])
+                self.freezes.mark_installed(blocks[:payload.n_full])
             else:
                 # a shared prefix splices installed-frozen pages: they start
                 # the frozen watermark, so they are never queued for a second
@@ -366,7 +365,7 @@ class DecodeWorker:
         install keeps the index current without per-page bookkeeping."""
         if self.prefix is None:
             return
-        frozen = self._frozen_pages if self.kv_spec is not None else None
+        frozen = self.freezes.installed if self.kv_spec is not None else None
         for i in self.sched.active_slots():
             st = self.sched.active[i]
             self.prefix.publish(st.req.prompt, self.slots[i].blocks, frozen)
@@ -406,14 +405,14 @@ class DecodeWorker:
         freezes are still polled — an async solve outliving its sequences
         must land (or be dropped) here, or a run loop keyed on
         ``has_work`` would wait on it forever."""
-        self._flush_freezes()
+        self.tree = self.freezes.flush(self.tree)
         if self.sched.active_slots():
             if self.speculate:
                 self._spec_decode_step(now_fn)
             else:
                 self._decode_step(now_fn)
         else:
-            self._poll_freezes()
+            self.tree = self.freezes.poll(self.tree)
         self._sample_cache()
 
     def _decode_step(self, now_fn) -> None:
@@ -425,7 +424,7 @@ class DecodeWorker:
         c["seq_decode_steps"] += len(active)
         with phase(tr, trk, "decode_step", step=c["decode_steps"],
                    active=len(active)):
-            self._poll_freezes()
+            self.tree = self.freezes.poll(self.tree)
             toks = np.zeros((len(self.slots), 1), np.int32)
             for i in active:
                 toks[i, 0] = self.slots[i].last_token
@@ -486,7 +485,7 @@ class DecodeWorker:
         idx = np.asarray(active)
         lens = self.lens[idx]
         pages = (lens + W - 1 + bs) // bs
-        frozen = self._frozen_mask[self.table[idx, :mb_used]]
+        frozen = self.freezes.mask[self.table[idx, :mb_used]]
         frozen &= np.arange(mb_used) < pages[:, None]
         c["kv_pages_read"] += int(pages.sum())
         c["kv_pages_read_frozen"] += int(np.count_nonzero(frozen))
@@ -500,7 +499,7 @@ class DecodeWorker:
         fused read takes them as codes and counts none."""
         if not fused:
             self.counters["kv_gather_frozen_pages"] += int(
-                np.count_nonzero(self._frozen_mask[table]))
+                np.count_nonzero(self.freezes.mask[table]))
 
     # ------------------------------------------------------- speculative
 
@@ -530,7 +529,7 @@ class DecodeWorker:
         c["seq_decode_steps"] += len(active)
         with phase(tr, trk, "decode_step", step=c["decode_steps"],
                    active=len(active), window=W):
-            self._poll_freezes()
+            self.tree = self.freezes.poll(self.tree)
             with phase(tr, self._trk_spec, "propose", k=k,
                        active=len(active)):
                 proposals = self.draft.propose(active, self.slots, k)
@@ -624,166 +623,32 @@ class DecodeWorker:
         s = self.slots[slot]
         full = int(new_len) // self.block_size
         if s.frozen_upto > full:
-            stale = {int(self.table[slot, j])
-                     for j in range(full, s.frozen_upto)}
-            tr = self.tracer
-            if tr.enabled:
-                for b in sorted(stale):
-                    sid = self._page_spans.pop(b, None)
-                    if sid is not None:
-                        tr.async_end(self._trk_freeze, "page_freeze", sid,
-                                     state="rolled_back", page=b)
-            self._freeze_bids = [b for b in self._freeze_bids
-                                 if b not in stale]
-            self._deferred_seen = min(self._deferred_seen,
-                                      len(self._freeze_bids))
-            for _, pending in self._pending_freezes:
-                pending.drop(stale)
+            self.freezes.release({int(self.table[slot, j])
+                                  for j in range(full, s.frozen_upto)},
+                                 "rolled_back")
             s.frozen_upto = full
         self.lens[slot] = new_len
 
     # ------------------------------------------------------------ freezing
 
-    def _poll_freezes(self, drain: bool = False) -> None:
-        """Install completed freezes; count the ones still overlapping this
-        decode step. drain=True blocks on the remainder (end of run)."""
-        still = []
-        installed_any = False
-        tr, c = self.tracer, self.counters
-        for step0, pending in self._pending_freezes:
-            if drain and not pending.is_ready():
-                # lint: sync(drain-only: end-of-run flush blocks by design)
-                jax.block_until_ready(pending.markers())
-            if pending.is_ready():
-                wait = c["decode_steps"] - step0
-                with phase(tr, self._trk_freeze, "install", c,
-                           "freeze_install_ns", wait_steps=wait) as p:
-                    self.tree = install_freeze(self.tree, pending)
-                    kept = pending.kept_pages()
-                    self._mark_frozen(kept)
-                    installed_any = True
-                    c["freeze_installs"] += 1
-                    c["freeze_overlap_steps"] += wait
-                    p.args["pages"] = len(kept)
-                    if tr.enabled:
-                        for b in kept:
-                            sid = self._page_spans.pop(b, None)
-                            if sid is not None:
-                                tr.async_end(self._trk_freeze, "page_freeze",
-                                             sid, state="installed", page=b)
-            else:
-                c["freeze_inflight_steps"] += 1
-                still.append((step0, pending))
-        self._pending_freezes = still
-        if installed_any:
-            # freshly installed pages just became shareable
-            self._publish_prefixes()
-
-    def _mark_frozen(self, blocks) -> None:
-        blocks = [int(b) for b in blocks]
-        self._frozen_pages.update(blocks)
-        self._frozen_mask[blocks] = True
-
-    def _unmark_frozen(self, blocks: set) -> None:
-        self._frozen_pages -= blocks
-        self._frozen_mask[list(blocks)] = False
-
     def _queue_freeze(self, slot: int) -> None:
         """Queue this sequence's just-filled pages for quantization; the
         worker iteration flushes the whole batch as ONE device dispatch
-        (_flush_freezes), so slots whose pages fill at the same step share
-        a solve."""
+        (``freezes.flush``), so slots whose pages fill at the same step
+        share a solve."""
         if self.kv_spec is None:
             return
         s = self.slots[slot]
         full = int(self.lens[slot]) // self.block_size
         if full > s.frozen_upto:
-            tr = self.tracer
-            for j in range(s.frozen_upto, full):
-                b = int(self.table[slot, j])
-                # a shared page is already installed (or already bid by the
-                # sequence that owns the solve) — never re-freeze: bids
-                # dedupe on block id
-                if b in self._frozen_pages or b in self._freeze_bids:
-                    continue
-                self._freeze_bids.append(b)
-                if tr.enabled:
-                    self._span_seq += 1
-                    self._page_spans[b] = self._span_seq
-                    tr.async_begin(self._trk_freeze, "page_freeze",
-                                   self._span_seq, page=b, slot=slot)
+            self.freezes.bid((self.table[slot, j]
+                              for j in range(s.frozen_upto, full)), slot)
             s.frozen_upto = full
-
-    def _flush_freezes(self) -> None:
-        """One batched solve for pages queued this iteration, rate-limited
-        to ``freeze_page_budget`` pages per decode step.
-
-        The budget is the backpressure valve: a prefill burst can queue a
-        whole prompt's worth of full pages at once, and solving them as one
-        chunk would run long enough to delay the next decode steps — the
-        remainder flushes on later iterations (deferred pages keep serving
-        exact fp until then, so correctness is unaffected) and
-        ``freeze_deferred_pages`` counts how often the valve engaged."""
-        if not self._freeze_bids:
-            return
-        tr = self.tracer
-        take = min(len(self._freeze_bids), self.freeze_page_budget)
-        with phase(tr, self._trk_freeze, "flush", self.counters,
-                   "freeze_flush_ns", pages=take,
-                   mode="async" if self.freeze_async else "sync"):
-            bids, self._freeze_bids = (self._freeze_bids[:take],
-                                       self._freeze_bids[take:])
-            if tr.enabled:
-                for b in bids:
-                    sid = self._page_spans.get(b)
-                    if sid is not None:
-                        tr.async_instant(self._trk_freeze, "page_freeze", sid,
-                                         state="dispatched")
-            # count each page's deferral once: the flush consumed ``take``
-            # pages off the queue front (the oldest, hence any already-counted
-            # ones first), so shrink the counted watermark by that before
-            # counting what now remains beyond it as newly deferred
-            self._deferred_seen = max(self._deferred_seen - take, 0)
-            newly = len(self._freeze_bids) - self._deferred_seen
-            if newly > 0:
-                self.counters["freeze_deferred_pages"] += newly
-            self._deferred_seen = len(self._freeze_bids)
-            if self.kv_spec.device_capable:
-                # pad to a power-of-two page count (repeating one page is a
-                # no-op at install) so the jitted solver compiles a handful of
-                # shapes instead of one per distinct flush size; the host
-                # fallback solves per page, where a duplicate is pure waste
-                bucket = 1 << (len(bids) - 1).bit_length()
-                bids = bids + [bids[-1]] * (bucket - len(bids))
-            if self.freeze_async:
-                pending = dispatch_freeze(self.tree, bids, self.kv_spec)
-                self._pending_freezes.append(
-                    (self.counters["decode_steps"], pending))
-                self.counters["freeze_pending_max"] = max(
-                    self.counters["freeze_pending_max"],
-                    len(self._pending_freezes))
-            else:
-                # synchronous solve + install, inside the flush's own time
-                with phase(tr, self._trk_freeze, "install",
-                           pages=len(set(bids)), wait_steps=0):
-                    self.tree = freeze_blocks(self.tree, bids, self.kv_spec,
-                                              stats=self.counters)
-                    self._mark_frozen(bids)
-                self._publish_prefixes()    # installed: shareable now
-                self.counters["freeze_installs"] += 1
-                if tr.enabled:
-                    # synchronous install: the lifecycle terminates here
-                    for b in sorted(set(bids)):
-                        sid = self._page_spans.pop(b, None)
-                        if sid is not None:
-                            tr.async_end(self._trk_freeze, "page_freeze", sid,
-                                         state="installed", page=b)
-            self.counters["freeze_dispatches"] += 1
 
     # ------------------------------------------------------------ teardown
 
     def _finish(self, st: SeqState, now: float) -> None:
-        slot, s = st.slot, self.slots[st.slot]
+        s = self.slots[st.slot]
         # a recompute-path resumption carries its first life's tokens as
         # prompt; stitch them back so the caller sees one output stream
         pre_out, pre_logits = self._resume_prefix.pop(st.req.id, ([], []))
@@ -791,41 +656,35 @@ class DecodeWorker:
         if self.record_logits and (pre_logits or s.logits):
             self.request_logits[st.req.id] = np.stack(pre_logits + s.logits)
         self.metrics.finish(st.req.id, now)
-        # drop one reference per page; teardown side effects (span drops,
-        # bid/frozen forgetting, thawing, index invalidation) scope to the
-        # pages actually RELEASED — a shared prefix page another live table
-        # still references keeps serving its codes
+        self.tracer.instant(self._trk_decode, "finish", rid=st.req.id,
+                            tokens=len(s.out))
+        self._release_slot(st, "dropped")
+
+    def _release_slot(self, st: SeqState, state: str) -> None:
+        """Tear a slot down: drop one reference per page and free the slot.
+
+        Side effects (index invalidation, span ends in ``state``, bid and
+        frozen forgetting, thawing) scope to the pages actually RELEASED —
+        a shared prefix page another live table still references keeps
+        serving its codes and its index entries."""
+        slot, s = st.slot, self.slots[st.slot]
         released = set(self.alloc.free(s.blocks))
         if self.prefix is not None:
             self.prefix.invalidate(released)
-        tr = self.tracer
-        if tr.enabled:
-            tr.instant(self._trk_decode, "finish", rid=st.req.id,
-                       tokens=len(s.out))
-            for b in sorted(released):
-                sid = self._page_spans.pop(b, None)
-                if sid is not None:
-                    tr.async_end(self._trk_freeze, "page_freeze", sid,
-                                 state="dropped", page=b)
-        self._freeze_bids = [b for b in self._freeze_bids
-                             if b not in released]
-        self._deferred_seen = min(self._deferred_seen, len(self._freeze_bids))
-        self._unmark_frozen(released)
-        for _, pending in self._pending_freezes:
-            pending.drop(released)
+        self.freezes.release(released, state)
         self.tree = thaw_blocks(self.tree, released)
         if self.draft is not None:
             self.draft.release(slot)
         self.table[slot] = 0
         self.lens[slot] = 0
-        s.rid, s.blocks, s.frozen_upto, s.out = None, [], 0, []
+        s.rid, s.blocks, s.frozen_upto, s.out, s.logits = None, [], 0, [], []
         s.rng, s.temperature, s.top_k = None, 0.0, 0
         self.last_attended.pop(slot, None)
         self.sched.release(st)
-        # the finisher may have been a chain's first publisher: invalidate
-        # dropped its keys even though an identical live copy (a survivor's
-        # own pages, same chain) may still be resident — re-publish so the
-        # NEXT lookup (prefill dispatch precedes any attach) still matches
+        # the slot may have been a chain's first publisher: invalidate
+        # dropped its keys though an identical live copy (a survivor's own
+        # pages, same chain) may still be resident — re-publish so the NEXT
+        # lookup (prefill dispatch precedes any attach) still matches
         self._publish_prefixes()
 
     # ------------------------------------------------------------ overload
@@ -841,11 +700,12 @@ class DecodeWorker:
         plus everything emitted; the re-prefill re-derives the KV (only
         chosen for unquantized greedy runs, where it is value-exact).
 
-        The teardown mirrors ``_finish`` minus the output/latency events —
-        the request stays live, only its residency changes — so every pool
-        invariant (freeze watermark, conservation, pending-solve staleness)
-        holds exactly as for a finished sequence. Open ``page_freeze``
-        spans terminate ``offloaded`` / ``dropped`` per mode.
+        The teardown is ``_finish``'s (``_release_slot``) minus the
+        output/latency events — the request stays live, only its residency
+        changes — so every pool invariant (freeze watermark, conservation,
+        pending-solve staleness) holds exactly as for a finished sequence.
+        Open ``page_freeze`` spans terminate ``offloaded`` / ``dropped``
+        per mode.
         """
         assert mode in ("restore", "recompute"), mode
         slot, s, req = st.slot, self.slots[st.slot], st.req
@@ -860,8 +720,9 @@ class DecodeWorker:
             # exclusively-owned page suffix (frozen_idx relative to it)
             sh = self.shared_prefix_pages(slot)
             full = n_tok // self.block_size
+            installed = self.freezes.installed
             frozen_idx = [j - sh for j in range(sh, full)
-                          if int(self.table[slot, j]) in self._frozen_pages]
+                          if int(self.table[slot, j]) in installed]
             payload = extract_resident_pages(
                 self.tree, s.blocks[sh:], n_tok - sh * self.block_size,
                 frozen_idx, block_size=self.block_size, tracer=tr)
@@ -882,10 +743,9 @@ class DecodeWorker:
             self.counters["offload_fp_equiv_bytes"] += payload.fp_equiv_bytes
             if tr.enabled:
                 for j in range(payload.n_pages):
-                    self._span_seq += 1
-                    entry.span_ids[j] = self._span_seq
+                    entry.span_ids[j] = self.freezes.next_span_id()
                     tr.async_begin(self._trk_freeze, "page_offload",
-                                   self._span_seq, rid=req.id, page_pos=j)
+                                   entry.span_ids[j], rid=req.id, page_pos=j)
         else:
             rem = req.max_new_tokens - st.generated
             resume = dataclasses.replace(
@@ -899,43 +759,8 @@ class DecodeWorker:
             self.counters["preempt_recomputes"] += 1
         tr.instant(self._trk_decode, "preempt", rid=req.id, slot=slot,
                    mode=mode, tokens=n_tok, pages=len(s.blocks))
-        # ref-drop every page; only the RELEASED ones (last reference was
-        # this victim's) tear down — a still-shared prefix page keeps its
-        # frozen install and index entries for the sequences serving it
-        released = set(self.alloc.free(s.blocks))
-        if self.prefix is not None:
-            self.prefix.invalidate(released)
-        if tr.enabled:
-            # literal per-branch states keep the page_freeze lifecycle
-            # statically checkable (repro.analysis span pass)
-            for b in sorted(released):
-                sid = self._page_spans.pop(b, None)
-                if sid is None:
-                    continue
-                if mode == "restore":
-                    tr.async_end(self._trk_freeze, "page_freeze", sid,
-                                 state="offloaded", page=b)
-                else:
-                    tr.async_end(self._trk_freeze, "page_freeze", sid,
-                                 state="dropped", page=b)
-        self._freeze_bids = [b for b in self._freeze_bids
-                             if b not in released]
-        self._deferred_seen = min(self._deferred_seen, len(self._freeze_bids))
-        self._unmark_frozen(released)
-        for _, pending in self._pending_freezes:
-            pending.drop(released)
-        self.tree = thaw_blocks(self.tree, released)
-        if self.draft is not None:
-            self.draft.release(slot)
-        self.table[slot] = 0
-        self.lens[slot] = 0
-        s.rid, s.blocks, s.frozen_upto, s.out, s.logits = None, [], 0, [], []
-        s.rng, s.temperature, s.top_k = None, 0.0, 0
-        self.last_attended.pop(slot, None)
-        self.sched.release(st)
-        # mirror _finish: re-register surviving duplicate chains whose keys
-        # the invalidate above may have dropped with the victim's pages
-        self._publish_prefixes()
+        self._release_slot(st,
+                           "offloaded" if mode == "restore" else "dropped")
         return entry
 
     def restore(self, st: SeqState, entry: ResumeEntry, now: float) -> None:
@@ -985,10 +810,10 @@ class DecodeWorker:
         self.lens[st.slot] = entry.n_tokens
         st.length, st.generated = entry.n_tokens, entry.generated
         self.last_attended[st.slot] = self.counters["decode_steps"]
-        self._mark_frozen(blocks[m + j] for j in entry.frozen_idx)
+        self.freezes.mark_installed(blocks[m + j] for j in entry.frozen_idx)
         # frozen_upto is the maximal frozen PREFIX; installs land in queue
         # order so the frozen set is a prefix in practice. If it ever
-        # weren't, _queue_freeze still skips the pages marked frozen just
+        # weren't, ``freezes.bid`` still skips the pages marked just
         # above: a frozen page is never solved again (its fp rows are not
         # its value). A quantized shared prefix is installed-frozen by
         # construction, so it extends the watermark from page 0.
@@ -1046,23 +871,21 @@ class DecodeWorker:
         if self.kv_spec is not None:
             # synchronous freeze: the restored watermark counts these pages
             # frozen from page 0, so they must be installed before decoding
-            self.tree = freeze_blocks(self.tree, blocks, self.kv_spec,
-                                      stats=self.counters)
-            self._mark_frozen(blocks)
+            self.tree = self.freezes.install_now(self.tree, blocks)
         return blocks
 
     def drain(self) -> None:
         """Flush every still-queued freeze and land in-flight solves (end
         of run — live sequences are gone, so latency no longer matters)."""
-        while self._freeze_bids:
-            self._flush_freezes()
-        self._poll_freezes(drain=True)
+        while self.freezes.bids:
+            self.tree = self.freezes.flush(self.tree)
+        self.tree = self.freezes.poll(self.tree, drain=True)
 
     def _sample_cache(self) -> None:
         allocated = (self.num_blocks - 1) - self.alloc.num_free
         # count *installed* pages: queued/in-flight solves still serve fp
         # at full width, so they must not book frozen-page bytes yet
-        frozen = len(self._frozen_pages)
+        frozen = len(self.freezes.installed)
         actual = (frozen * self._pb["frozen"]
                   + (allocated - frozen) * self._pb["fp"])
         occ = allocated / (self.num_blocks - 1)

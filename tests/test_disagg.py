@@ -457,7 +457,7 @@ def test_freeze_page_budget_defers_burst(qwen_reduced):
     c = eng.counters
     assert c["freeze_deferred_pages"] > 0, "budget valve never engaged"
     assert c["freeze_installs"] == c["freeze_dispatches"] > 0
-    assert not eng._pending_freezes
+    assert not eng.worker.freezes.inflight
     # the same burst with an uncapped budget defers nothing
     eng2 = ContinuousBatchingEngine(params, cfg, max_slots=2, block_size=8,
                                     max_seq_len=48, kv_quant="kmeans_ls@16",
@@ -481,7 +481,7 @@ def test_disagg_async_freeze_outliving_sequences_drains(qwen_reduced):
                        max_new_tokens=2)
     assert len(out[0]) == 2
     dw = eng.decode[0]
-    assert not dw._pending_freezes and not dw._freeze_bids
+    assert dw.freezes.idle
     assert (dw.counters["freeze_installs"]
             == dw.counters["freeze_dispatches"] > 0)
 

@@ -531,7 +531,7 @@ class _ReadsTracer(NullTracer):
             pages = -(-(n + self.W) // w.block_size)
             r["kv_pages_read"] += pages
             r["kv_pages_read_frozen"] += sum(
-                int(b) in w._frozen_pages for b in w.table[i, :pages])
+                int(b) in w.freezes.installed for b in w.table[i, :pages])
             r["kv_rows_used"] += n
             r["kv_rows_reserved"] += len(w.slots[i].blocks) * w.block_size
 

@@ -225,8 +225,8 @@ def assert_pool_partition(worker):
     assert not (free & used), "page both free and used"
     assert free | used == set(range(1, worker.num_blocks)), "page leaked"
     # frozen bookkeeping never refers to an unallocated page
-    assert worker._frozen_pages <= used
-    assert set(worker._freeze_bids) <= used
+    assert worker.freezes.installed <= used
+    assert set(worker.freezes.bids) <= used
     # sharing only splices published *prefix* runs, so refcounts are
     # monotone non-increasing along every table
     for s in worker.slots:
@@ -259,7 +259,7 @@ def _check_conservation(qwen_reduced, reqs, speculate):
     # that finished with freeze solves still in flight (budget=1 defers)
     assert sorted(eng.outputs) == list(range(len(reqs)))
     assert eng.alloc.num_free == eng.num_blocks - 1
-    assert not w._pending_freezes and not w._freeze_bids
+    assert w.freezes.idle
     if speculate:
         assert not any(w.draft.blocks)
         assert w.draft.alloc.num_free == w.draft.num_blocks - 1
@@ -342,7 +342,7 @@ def _check_tiered_conservation(qwen_reduced, reqs, speculate):
     assert sorted(eng.outputs) == list(range(len(reqs)))
     assert eng.alloc.num_free == eng.num_blocks - 1
     assert len(om.store) == 0 and not om.resume and not om.deferred
-    assert not w._pending_freezes and not w._freeze_bids
+    assert w.freezes.idle
     # quantized serving must never pick the recompute path (not exact)
     assert s["preempt_recomputes"] == 0
     return s["preemptions"], outlived[0]
@@ -416,7 +416,7 @@ def _check_refcount_conservation(qwen_reduced, shapes, speculate):
     assert sorted(eng.outputs) == list(range(len(requests)))
     assert eng.alloc.num_free == eng.num_blocks - 1
     assert len(om.store) == 0 and not om.resume and not om.deferred
-    assert not w._pending_freezes and not w._freeze_bids
+    assert w.freezes.idle
     assert len(w.prefix) == 0, "prefix index must drain with the pool"
     return s
 

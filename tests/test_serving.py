@@ -685,7 +685,7 @@ def test_device_freeze_async_no_host_solves(qwen_reduced):
     assert c["freeze_dispatches"] > 0
     assert c["host_page_solves"] == 0, "kmeans_ls must not solve on host"
     assert c["freeze_installs"] == c["freeze_dispatches"]
-    assert not eng._pending_freezes          # run() drains
+    assert not eng.worker.freezes.inflight   # run() drains
     assert c["decode_steps"] > 0 and c["freeze_overlap_steps"] >= 0
     # non-device methods keep the host fallback and are counted (the
     # request must outlive the iteration flush or its queued pages are

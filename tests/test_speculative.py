@@ -176,8 +176,8 @@ def _frozen_watermark_ok(w):
     for slot, s in enumerate(w.slots):
         for j, b in enumerate(s.blocks):
             page_slot[int(b)] = (slot, j)
-    suspect = set(w._frozen_pages) | set(w._freeze_bids)
-    for _, pending in w._pending_freezes:
+    suspect = w.freezes.installed | set(w.freezes.bids)
+    for _, pending in w.freezes.inflight:
         suspect |= {int(b) for b in pending.bids[pending.keep]}
     for b in suspect:
         if b not in page_slot:      # just-freed page awaiting drop/install
@@ -239,11 +239,11 @@ def test_rollback_unqueues_freeze_bids(qwen_reduced):
     # pretend the verify wrote optimistically through 11 rows (3 pages)
     w.lens[0] = 11
     w._queue_freeze(0)
-    assert w._freeze_bids == [3, 5] and s.frozen_upto == 2
+    assert w.freezes.bids == [3, 5] and s.frozen_upto == 2
     # rollback to 6 accepted rows: page 5 (rows 4..7) is past the
     # watermark and must leave the queue; page 3 (rows 0..3) stays
     w._rollback_slot(0, 6)
-    assert w._freeze_bids == [3]
+    assert w.freezes.bids == [3]
     assert s.frozen_upto == 1
     assert int(w.lens[0]) == 6
 
